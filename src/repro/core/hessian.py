@@ -26,7 +26,6 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -88,11 +87,21 @@ def _sharded_partial_fns(mesh, axis: str):
         part = jnp.sum(xf * xf, axis=0)
         return jax.lax.psum(part, axis)
 
-    full = jax.jit(shard_map(_full, mesh=mesh, in_specs=P(axis, None),
-                             out_specs=P(), check_rep=False))
-    diag = jax.jit(shard_map(_diag, mesh=mesh, in_specs=P(axis, None),
-                             out_specs=P(), check_rep=False))
+    full = jax.jit(jax.shard_map(_full, mesh=mesh, in_specs=P(axis, None),
+                                 out_specs=P(), check_vma=False))
+    diag = jax.jit(jax.shard_map(_diag, mesh=mesh, in_specs=P(axis, None),
+                                 out_specs=P(), check_vma=False))
     return full, diag
+
+
+def data_mesh(n_devices: int, axis: str = "data"):
+    """1-D mesh over the first ``n_devices`` devices for
+    ``accumulate_sharded``. Its axis is ``Auto``: the sharded partials are
+    merged by a psum inside shard_map and leave it replicated, so nothing
+    downstream (the GPTVQ sweep) should carry an explicit sharding type —
+    ``jax.make_mesh``'s default ``Explicit`` axes would propagate one."""
+    return jax.make_mesh((n_devices,), (axis,),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def _shard_rows(x: jax.Array, c: int, n_dev: int):

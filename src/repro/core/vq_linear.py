@@ -444,7 +444,7 @@ def fused_matmul(x: jax.Array, fvl: FusedVQLinear, *, impl: str | None = None,
             interpret = jax.default_backend() != "tpu"
         y = vq_dequant_matmul(
             x2, fvl.words, fvl.codebooks_f, fvl.scales,
-            d=fvl.d, k_c=fvl.k, code_bits=fvl.code_bits,
+            d=fvl.d, k_c=fvl.k,
             container_bits=packing.container_bits(fvl.code_bits),
             rows_per_band=fvl.rows_per_band, group_cols=fvl.group_cols,
             scale_block=fvl.scale_block, tile_m=tile_m, tile_n=tile_n,

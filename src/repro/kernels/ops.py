@@ -1,7 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 ``use_pallas`` selects the execution path:
-  * True  — pl.pallas_call (TPU target; interpret=True on CPU for tests)
+  * True  — pl.pallas_call, compiled for the TPU. ``interpret`` defaults
+            to False: a CPU caller (the tests) must ask for interpret mode
+            explicitly, so no TPU caller lands in it by omission.
   * False — the pure-XLA fallback (used by the multi-pod dry-run: Pallas TPU
             lowering is unavailable on the host-CPU dry-run platform).
 """
@@ -18,7 +20,7 @@ from repro.kernels.vq_dequant_matmul import vq_dequant_matmul
 
 
 def vql_matmul(x: jax.Array, vql, *, use_pallas: bool = True,
-               interpret: bool = True, tile_m: int = 128, tile_n: int = 128,
+               interpret: bool = False, tile_m: int = 128, tile_n: int = 128,
                tile_k: int = 256) -> jax.Array:
     """y = x @ W^T for a VQLinear, fused on TPU.
 
@@ -36,7 +38,7 @@ def vql_matmul(x: jax.Array, vql, *, use_pallas: bool = True,
     if use_pallas:
         return vq_dequant_matmul(
             x, vql.words, vql.codebooks_f, vql.scales,
-            d=vql.d, k_c=vql.k, code_bits=vql.code_bits,
+            d=vql.d, k_c=vql.k,
             container_bits=packing.container_bits(vql.code_bits),
             rows_per_band=vql.rows_per_band, group_cols=vql.group_cols,
             scale_block=vql.scale_block, tile_m=tile_m,
@@ -51,7 +53,7 @@ def vql_matmul(x: jax.Array, vql, *, use_pallas: bool = True,
 def paged_attention(q, k_pool, v_pool, page_table, pos, *,
                     k_scale=None, v_scale=None,
                     k_codebook=None, v_codebook=None,
-                    use_pallas: bool = True, interpret: bool = True):
+                    use_pallas: bool = True, interpret: bool = False):
     """Fused paged-attention decode: one query token per slot attends over
     its page-table-mapped KV blocks (kpos <= pos masking) without
     materializing the logical per-slot view. q (B, H, hd) -> (B, H, hd).
@@ -77,7 +79,7 @@ def paged_attention(q, k_pool, v_pool, page_table, pos, *,
 
 
 def assign(x, hw, codebook, *, use_pallas: bool = True,
-           interpret: bool = True, tile_n: int = 1024):
+           interpret: bool = False, tile_n: int = 1024):
     if use_pallas:
         n = x.shape[0]
         t = min(tile_n, n)

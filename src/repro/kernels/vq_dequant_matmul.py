@@ -5,11 +5,6 @@ bit-packed index matrix is the HBM payload (2-4.5 bits/weight); codebooks
 live in VMEM; decode happens on-chip and the reconstructed tile feeds the
 MXU directly, so the dense weight matrix never round-trips through HBM.
 
-Centroid lookup uses the one-hot-matmul trick (``one_hot(codes) @ codebook``)
-instead of a gather: TPU gathers serialize on the scalar unit, whereas the
-one-hot contraction runs on the MXU at full tile throughput — this is the
-core 'rethink the GPU/CPU algorithm for the TPU memory hierarchy' decision.
-
 Layout contract (matches core/vq_linear.VQLinear):
   x          (M, K)                      activations
   words      (N, K/d * bits / 32)        packed uint32 codes, row-major
@@ -17,19 +12,28 @@ Layout contract (matches core/vq_linear.VQLinear):
   scales     (N, K/Ns) fp32, optional    blockwise normalization plane
 with N = n_bands * rows_per_band, K = n_cg * group_cols.
 
-Shape handling (serving reality, not benchmark reality):
-  * M is padded up to a sublane-aligned tile (decode batches are 1..8 rows;
-    the old ``assert M % tile_m == 0`` rejected them) and the output is
-    sliced back.
-  * tile_n / tile_k are snapped DOWN to the largest band- / group-aligned
-    divisors of N / K, so ragged layer shapes never trip an assert. Row
-    bands always divide N and column groups always divide K, so a legal
-    tiling always exists; k-tiles additionally snap to the uint32 word
-    boundary of the packed rows.
-  * Blockwise normalization scales enter as a (N, K/Ns) fp32 plane
-    (pre-expanded once at engine load by core/vq_linear.prepare_fused) and
-    multiply the decoded tile in VMEM — scale_block != 0 recipes no longer
-    fall off the fused path.
+Piece decomposition (no lane interleaving in VMEM). Word w of a row holds
+``lanes`` codes, code l covering columns ``(w*lanes + l)*d + e``. With
+P = lanes*d, column k = w*P + p belongs to "piece" p = l*d + e, so
+``x @ W.T = sum_p x[:, p::P] @ W_p.T`` where W_p (N, K/P) is aligned with
+the word grid: W_p[n, w] = codebook[group(w), band(n), code_l[n, w], e].
+The wrapper hands x over piece-major; in the kernel each piece is a shift
+and mask of the word tile, a k_c-way select against per-entry value
+planes, and one MXU matmul.
+
+Value planes. Entry (c, e) of every (band, group) codebook in the tile is
+broadcast to a (tile_n, words) plane by two 0/1 expansion matmuls
+(rows -> bands, words -> groups) at HIGHEST precision, which reproduce the
+f32 entries exactly. The planes are built once per grid step into VMEM
+scratch and read by every piece. A blockwise scale plane is expanded the
+same way (per piece when a scale block is narrower than one word).
+
+Tiling follows the TPU rule for the last two block dims (a multiple of
+(8, 128), or the whole array dim): tile_n is a band-aligned divisor of N
+that is a multiple of 128 with a multiple-of-8 band count (or all of N),
+and tile_k is a group-aligned divisor of K whose words per row, and scale
+columns, are multiples of 128 (or all of K). M is padded to a multiple of
+8 and sliced back.
 """
 from __future__ import annotations
 
@@ -38,99 +42,127 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_HI = jax.lax.Precision.HIGHEST
 
 
-def _kernel(x_ref, w_ref, c_ref, *rest, d, k_c, code_bits, container_bits,
-            rows_per_band, scale_block, n_k_tiles):
+def _band_mask(shape, width, axis, col_scale=1, col_offset=0):
+    """0/1 f32 expansion matrix: entry (i, j) is 1 where the index along
+    the other axis, times ``col_scale`` plus ``col_offset``, falls in band
+    ``[k*width, (k+1)*width)`` of the index k along ``axis``."""
+    band = jax.lax.broadcasted_iota(jnp.int32, shape, axis) * width
+    pos = (jax.lax.broadcasted_iota(jnp.int32, shape, 1 - axis) * col_scale
+           + col_offset)
+    return ((pos >= band) & (pos < band + width)).astype(jnp.float32)
+
+
+def _kernel(x_ref, w_ref, c_ref, *rest, d, k_c, container_bits,
+            rows_per_band, words_per_group, scale_block):
     if scale_block:
-        s_ref, o_ref = rest
+        s_ref, o_ref, v_scr = rest
     else:
-        (o_ref,) = rest
+        o_ref, v_scr = rest
     kk = pl.program_id(2)
 
     @pl.when(kk == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[...]            # (tm, tk)
-    words = w_ref[...]        # (tn, wk) uint32
-    C = c_ref[...]            # (gk, bands_t, k_c, d) fp32
-
-    tn, wk = words.shape
-    tm, tk = x.shape
+    tn, wk = w_ref.shape
+    bands_t, gk = c_ref.shape[-2:]
     lanes = 32 // container_bits
-    spans = tk // d           # codes per row in this k-tile
-    bands_t = tn // rows_per_band
+    P = lanes * d
 
-    # unpack: (tn, wk) -> (tn, wk, lanes) -> (tn, spans)
-    shifts = (jnp.arange(lanes, dtype=jnp.uint32) * container_bits)
-    mask = jnp.uint32(2**container_bits - 1)
-    codes = ((words[:, :, None] >> shifts[None, None, :]) & mask)
-    codes = codes.reshape(tn, spans).astype(jnp.int32)
+    # value planes: v_scr[c*d + e][n, w] = codebook[group(w), band(n), c, e]
+    e_rows = _band_mask((tn, bands_t), rows_per_band, axis=1)
+    e_cols = _band_mask((gk, wk), words_per_group, axis=0)
+    unroll = k_c <= 16   # large codebooks loop instead of unrolling
 
-    # decode via one-hot matmul per row-band (MXU-friendly; no gathers)
-    gk = C.shape[0]           # column-groups covered by this k-tile
-    spans_pg = spans // gk
-    codes_b = codes.reshape(bands_t, rows_per_band, gk, spans_pg)
-    onehot = (codes_b[..., None] ==
-              jnp.arange(k_c, dtype=jnp.int32)).astype(jnp.float32)
-    # (bands_t, rg, gk, spans_pg, k_c) x (gk, bands_t, k_c, d)
-    w_dec = jax.lax.dot_general(
-        onehot.transpose(2, 0, 1, 3, 4).reshape(gk, bands_t, -1, k_c),
-        C,
-        dimension_numbers=(((3,), (2,)), ((0, 1), (0, 1))),
-    )  # (gk, bands_t, rg*spans_pg, d)
-    w_tile = (
-        w_dec.reshape(gk, bands_t, rows_per_band, spans_pg, d)
-        .transpose(1, 2, 0, 3, 4)
-        .reshape(tn, tk)
-    )
-    if scale_block:
-        s = s_ref[...]        # (tn, tk // Ns)
-        w_tile = (w_tile.reshape(tn, tk // scale_block, scale_block)
-                  * s[:, :, None]).reshape(tn, tk)
+    def value_plane(j, carry):
+        by_band = jnp.dot(c_ref[0, j], e_cols, precision=_HI,
+                          preferred_element_type=jnp.float32)
+        v_scr[j] = jnp.dot(e_rows, by_band, precision=_HI,
+                           preferred_element_type=jnp.float32)
+        return carry
 
-    o_ref[...] += jax.lax.dot_general(
-        x.astype(jnp.float32), w_tile,
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    jax.lax.fori_loop(0, k_c * d, value_plane, 0, unroll=unroll)
+
+    def scale_plane(p):
+        # s[n, (w*P + p) // Ns] expanded onto the (tn, wk) word grid
+        e_s = _band_mask((s_ref.shape[1], wk), scale_block, axis=0,
+                         col_scale=P, col_offset=p)
+        return jnp.dot(s_ref[...], e_s, precision=_HI,
+                       preferred_element_type=jnp.float32)
+
+    shared_scale = None
+    if scale_block and scale_block % P == 0:
+        shared_scale = scale_plane(0)
+
+    words = w_ref[...]
+    mask = 2 ** container_bits - 1
+    acc = jnp.zeros(o_ref.shape, jnp.float32)
+    for l in range(lanes):
+        code = jax.lax.shift_right_logical(words, l * container_bits) & mask
+        for e in range(d):
+            p = l * d + e
+            w_p = jax.lax.fori_loop(
+                0, k_c,
+                lambda c, w_p: jnp.where(code == c, v_scr[c * d + e], w_p),
+                jnp.zeros((tn, wk), jnp.float32), unroll=unroll)
+            if scale_block:
+                w_p = w_p * (shared_scale if shared_scale is not None
+                             else scale_plane(p))
+            acc += jax.lax.dot_general(
+                x_ref[p], w_p, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+    o_ref[...] += acc
 
 
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
+def _pick(legal: list[int], want: int) -> int:
+    """Largest legal tile <= want, else the smallest legal one."""
+    below = [t for t in legal if t <= want]
+    return max(below) if below else min(legal)
+
+
 def _snap_tile_n(N: int, rows_per_band: int, tile_n: int) -> int:
-    """Largest band-aligned divisor of N that fits in tile_n (>= one band)."""
-    bands = N // rows_per_band
-    for bt in range(min(bands, max(1, tile_n // rows_per_band)), 0, -1):
-        if bands % bt == 0:
-            return bt * rows_per_band
-    return rows_per_band
+    """Band-aligned divisor of N the TPU tiling accepts for the words,
+    scale and output blocks (lanes: %128 or all of N) and the codebook
+    block (bands: %8 or all of them)."""
+    n_bands = N // rows_per_band
+    legal = [bt * rows_per_band for bt in range(1, n_bands + 1)
+             if n_bands % bt == 0
+             and ((bt * rows_per_band) % 128 == 0 and bt % 8 == 0
+                  or bt == n_bands)]
+    return _pick(legal, tile_n)
 
 
-def _snap_tile_k(K: int, group_cols: int, d: int, lanes: int,
+def _snap_tile_k(K: int, group_cols: int, pieces: int, scale_block: int,
                  tile_k: int) -> int:
-    """Largest group-aligned divisor of K fitting tile_k whose per-row code
-    count lands on a packed-word boundary; falls back to growing the tile
-    (full K always aligns — rows are packed whole)."""
+    """Group-aligned divisor of K whose words per row (tile_k / pieces)
+    and scale columns (tile_k / scale_block) are multiples of 128, or all
+    of K (a whole packed row is always a legal block)."""
     n_cg = K // group_cols
-    cap = min(n_cg, max(1, tile_k // group_cols))
-    for gk in range(cap, 0, -1):
-        if n_cg % gk == 0 and (gk * group_cols // d) % lanes == 0:
-            return gk * group_cols
-    for gk in range(cap + 1, n_cg + 1):
-        if n_cg % gk == 0 and (gk * group_cols // d) % lanes == 0:
-            return gk * group_cols
-    raise ValueError(
-        f"no word-aligned k-tiling for K={K} cg={group_cols} d={d} "
-        f"lanes={lanes}")
+    legal = []
+    for gk in range(1, n_cg + 1):
+        tk = gk * group_cols
+        if n_cg % gk:
+            continue
+        ok = tk % pieces == 0 and (tk // pieces) % 128 == 0
+        if scale_block:
+            ok = ok and tk % scale_block == 0 and (tk // scale_block) % 128 == 0
+        if ok or tk == K:
+            legal.append(tk)
+    return _pick(legal, tile_k)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("d", "k_c", "code_bits", "container_bits",
+    static_argnames=("d", "k_c", "container_bits",
                      "rows_per_band", "group_cols", "scale_block", "tile_m",
                      "tile_n", "tile_k", "interpret"),
 )
@@ -142,7 +174,6 @@ def vq_dequant_matmul(
     *,
     d: int,
     k_c: int,
-    code_bits: int,
     container_bits: int,
     rows_per_band: int,
     group_cols: int,
@@ -155,48 +186,57 @@ def vq_dequant_matmul(
     """y = x @ dequant(words, codebooks).T ; returns (M, N) fp32.
 
     ``scales`` (required iff scale_block != 0) is the pre-expanded blockwise
-    normalization plane (N, K // scale_block)."""
+    normalization plane (N, K // scale_block). ``tile_*`` are upper
+    bounds; the tiles actually used are snapped to legal TPU blocks."""
     M, K = x.shape
     N = words.shape[0]
     assert (scales is not None) == bool(scale_block)
     lanes = 32 // container_bits
+    P = lanes * d
+    assert group_cols % P == 0, (
+        f"column groups ({group_cols}) must hold whole words ({P} cols)")
+    n_bands = N // rows_per_band
 
     tile_n = _snap_tile_n(N, rows_per_band, tile_n)
-    tile_k = _snap_tile_k(K, group_cols, d, lanes, tile_k)
-    if scale_block:
-        assert tile_k % scale_block == 0, (tile_k, scale_block)
-    # decode-shaped M: pad rows to a sublane-aligned tile, slice after
+    tile_k = _snap_tile_k(K, group_cols, P, scale_block, tile_k)
     tile_m = min(tile_m, _round_up(M, 8))
     Mp = _round_up(M, tile_m)
-    if Mp != M:
-        x = jnp.pad(x, ((0, Mp - M), (0, 0)))
-
-    wk = tile_k // d // lanes  # words per row per k-tile
-    gk = tile_k // group_cols
+    wk, gk = tile_k // P, tile_k // group_cols
     bands_t = tile_n // rows_per_band
-    grid = (Mp // tile_m, N // tile_n, K // tile_k)
+    n_kt = K // tile_k
+    grid = (Mp // tile_m, N // tile_n, n_kt)
+
+    # piece-major activations: xp[p, m, w] = x[m, w*P + p]
+    xp = jnp.pad(x.astype(jnp.float32), ((0, Mp - M), (0, 0)))
+    xp = xp.reshape(Mp, K // P, P).transpose(2, 0, 1)
+    # codebooks per k-tile, entry-major: cb[t, c*d + e, band, g]
+    cb = codebooks.astype(jnp.float32).reshape(
+        n_kt, gk, n_bands, k_c, d).transpose(0, 3, 4, 2, 1).reshape(
+        n_kt, k_c * d, n_bands, gk)
 
     in_specs = [
-        pl.BlockSpec((tile_m, tile_k), lambda i, j, kk: (i, kk)),
+        pl.BlockSpec((P, tile_m, wk), lambda i, j, kk: (0, i, kk)),
         pl.BlockSpec((tile_n, wk), lambda i, j, kk: (j, kk)),
-        pl.BlockSpec((gk, bands_t, k_c, d), lambda i, j, kk: (kk, j, 0, 0)),
+        pl.BlockSpec((1, k_c * d, bands_t, gk),
+                     lambda i, j, kk: (kk, 0, j, 0)),
     ]
-    operands = [x, words, codebooks]
+    operands = [xp, jax.lax.bitcast_convert_type(words, jnp.int32), cb]
     if scale_block:
         in_specs.append(
             pl.BlockSpec((tile_n, tile_k // scale_block),
                          lambda i, j, kk: (j, kk)))
-        operands.append(scales)
+        operands.append(scales.astype(jnp.float32))
 
     y = pl.pallas_call(
         functools.partial(
-            _kernel, d=d, k_c=k_c, code_bits=code_bits,
-            container_bits=container_bits, rows_per_band=rows_per_band,
-            scale_block=scale_block, n_k_tiles=grid[2]),
+            _kernel, d=d, k_c=k_c, container_bits=container_bits,
+            rows_per_band=rows_per_band,
+            words_per_group=group_cols // P, scale_block=scale_block),
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((tile_m, tile_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, N), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((k_c * d, tile_n, wk), jnp.float32)],
         interpret=interpret,
     )(*operands)
-    return y[:M] if Mp != M else y
+    return y[:M]

@@ -37,9 +37,11 @@ import time
 
 import jax
 
+from repro import compile_cache
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.configs import ARCHS, SMOKE
 from repro.core import adapters
+from repro.core import hessian as hes
 from repro.core.bpv import PAPER_SETTINGS
 from repro.core.pipeline import quantize_model
 from repro.core.recipe import PRESET_RECIPES, QuantRecipe, get_recipe
@@ -50,6 +52,7 @@ from repro.train.loss import perplexity
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama2-7b")
     ap.add_argument("--smoke", action="store_true")
@@ -126,7 +129,7 @@ def main():
         recipe = recipe.with_solver(args.solver)
     mesh = None
     if args.hessian_mesh > 1:
-        mesh = jax.make_mesh((args.hessian_mesh,), ("data",))
+        mesh = hes.data_mesh(args.hessian_mesh)
     budget = f" budget={args.budget_bpv}bpv" if args.budget_bpv else ""
     solver = f" solver={args.solver}" if args.solver else ""
     print(f"arch={cfg.name} recipe={recipe.name or 'custom'}{budget}"

@@ -12,6 +12,7 @@ import time
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.configs import ARCHS, SMOKE
 from repro.core.pipeline import quantize_model
 from repro.core.recipe import get_recipe
@@ -22,6 +23,7 @@ from repro.serve.engine import Engine, Request
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama2-7b")
     ap.add_argument("--smoke", action="store_true")

@@ -260,8 +260,6 @@ def apply(p, cfg: ModelConfig, x: jax.Array):
 
 def _apply_ep(p, cfg: ModelConfig, x: jax.Array, mesh):
     """shard_map expert parallelism: local dispatch, psum combine."""
-    from jax.experimental.shard_map import shard_map
-
     B, S, D = x.shape
     E, K = cfg.n_experts, cfg.n_experts_active
     C = capacity(S, cfg)
@@ -340,9 +338,9 @@ def _apply_ep(p, cfg: ModelConfig, x: jax.Array, mesh):
         P(batch_ax, None, None),          # tokens over DP axes
     )
     out_specs = (P(batch_ax, None, None), P())
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False)
+        check_vma=False)
     gate_arg = p["w_gate"] if gated else p["w_in"]  # ignored when not gated
     y, aux = fn(p["router"].astype(jnp.float32), p["w_in"],
                 gate_arg, p["w_out"], x)

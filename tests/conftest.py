@@ -1,15 +1,14 @@
 """Shared test-session plumbing.
 
-jax 0.4.37's CPU backend segfaults inside ``backend_compile`` once a few
-hundred jitted executables accumulate in one process (the unsharded
-tier-1 run started crashing at the same test, twice, at ~270 compiled
-functions after PR 6 grew the suite past that point; every package-level
-subset — including a 164-test kernels+serve+substrate run — passes in
-isolation, and the host has >100 GB free, so this is a compiler-state
-cliff, not a test bug or OOM). Clearing the compilation caches whenever
-the session crosses a test-package boundary keeps the live-executable
-count bounded to one package's worth without changing any test; the CI
-shards already run packages in separate processes and never hit it.
+Clearing JAX's compilation caches whenever a test process crosses a
+test-package boundary keeps its live-executable count bounded to one
+package's worth, without changing any test. It was added after an earlier
+JAX's CPU backend segfaulted inside ``backend_compile`` once ~270 jitted
+executables had accumulated in a single-process tier-1 run (every
+package-level subset passed alone, so it was a compiler-state cliff, not
+a test bug or OOM). It stays under jax 0.9: no single-process run of the
+whole suite without it has been made to show the cliff is gone, and it
+also caps each xdist worker's memory, which matters on a shared host.
 """
 import jax
 import pytest

@@ -44,7 +44,7 @@ class TestVQDequantMatmul:
         x = jax.random.normal(jax.random.PRNGKey(1), (M, K))
         from repro.kernels.vq_dequant_matmul import vq_dequant_matmul
         y = vq_dequant_matmul(
-            x, words, C, d=d, k_c=2 ** (d * bits), code_bits=code_bits,
+            x, words, C, d=d, k_c=2 ** (d * bits),
             container_bits=packing.container_bits(code_bits),
             rows_per_band=rg, group_cols=cg,
             tile_m=min(8, M), tile_n=min(64, N), tile_k=min(256, K),
@@ -63,7 +63,7 @@ class TestVQDequantMatmul:
         x = jax.random.normal(jax.random.PRNGKey(1), (8, 256)).astype(dtype)
         from repro.kernels.vq_dequant_matmul import vq_dequant_matmul
         y = vq_dequant_matmul(
-            x, words, C, d=2, k_c=16, code_bits=code_bits,
+            x, words, C, d=2, k_c=16,
             container_bits=4, rows_per_band=8, group_cols=256,
             tile_m=8, tile_n=64, tile_k=256, interpret=True)
         y_ref = ref.vq_dequant_matmul_ref(
@@ -97,7 +97,7 @@ class TestVQDequantMatmul:
         x = jax.random.normal(jax.random.PRNGKey(7), (M, 256))
         from repro.kernels.vq_dequant_matmul import vq_dequant_matmul
         y = vq_dequant_matmul(
-            x, words, C, d=2, k_c=16, code_bits=code_bits,
+            x, words, C, d=2, k_c=16,
             container_bits=4, rows_per_band=8, group_cols=256,
             tile_m=128, tile_n=64, tile_k=256, interpret=True)
         assert y.shape == (M, 64)
@@ -117,7 +117,7 @@ class TestVQDequantMatmul:
         x = jax.random.normal(jax.random.PRNGKey(9), (4, 384))
         from repro.kernels.vq_dequant_matmul import vq_dequant_matmul
         y = vq_dequant_matmul(
-            x, words, C, d=2, k_c=16, code_bits=code_bits,
+            x, words, C, d=2, k_c=16,
             container_bits=4, rows_per_band=8, group_cols=128,
             tile_m=128, tile_n=128, tile_k=256, interpret=True)
         y_ref = ref.vq_dequant_matmul_ref(
@@ -138,7 +138,7 @@ class TestVQDequantMatmul:
         x = jax.random.normal(jax.random.PRNGKey(12), (8, 512))
         from repro.kernels.vq_dequant_matmul import vq_dequant_matmul
         y = vq_dequant_matmul(
-            x, words, C, scales, d=2, k_c=16, code_bits=code_bits,
+            x, words, C, scales, d=2, k_c=16,
             container_bits=4, rows_per_band=8, group_cols=256,
             scale_block=Ns, tile_m=8, tile_n=64, tile_k=tk, interpret=True)
         y_ref = ref.vq_dequant_matmul_ref(
