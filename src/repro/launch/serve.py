@@ -81,7 +81,8 @@ def main():
                          "as JSON here")
     ap.add_argument("--trace-dir", default=None,
                     help="capture a jax profiler trace of the serve loop; "
-                         "host spans become StepTraceAnnotations")
+                         "each tick is the step serve.tick and each phase "
+                         "an annotation serve.<phase>")
     args = ap.parse_args()
 
     cfg = (SMOKE if args.smoke else ARCHS)[args.arch]

@@ -437,11 +437,12 @@ def apply(
         o = pre_out(p, cfg, x, pos=pos, causal=causal, use_rope=use_rope,
                     flash_threshold=flash_threshold)
         return cm.matmul(o, p["wo"]).astype(x.dtype), None
-    q, k, v = _project_qkv(p, cfg, x)
-    pos_arr = cm.position_ids(pos, B, S)  # (B, S)
-    if use_rope:
-        q = cm.apply_rope(q, pos_arr, cfg.rope_theta)
-        k = cm.apply_rope(k, pos_arr, cfg.rope_theta)
+    with jax.named_scope("attn_qkv"):
+        q, k, v = _project_qkv(p, cfg, x)
+        pos_arr = cm.position_ids(pos, B, S)  # (B, S)
+        if use_rope:
+            q = cm.apply_rope(q, pos_arr, cfg.rope_theta)
+            k = cm.apply_rope(k, pos_arr, cfg.rope_theta)
 
     if isinstance(cache, PagedKVCache):
         return _paged_apply(p, cache, q, k, v, pos_arr, x.dtype,
@@ -524,46 +525,61 @@ def _paged_apply(p, cache: PagedKVCache, q, k, v, pos_arr, out_dtype,
         kv_bits = kvq.infer_bits(cache.k.shape[-1], q.shape[-1])
     else:
         kv_bits = kvq.PASSTHROUGH_BITS
-    page = pos_arr // page_size
-    blk = jnp.take_along_axis(
-        cache.page_table, jnp.minimum(page, n_pages - 1), axis=1)  # (B, S)
-    # positions past the table extent (a padded prefill chunk can overhang
-    # max_len) go to scratch — clipping them into the last page would
-    # overwrite live K/V
-    blk = jnp.where(page < n_pages, blk, 0)
-    off = pos_arr % page_size
-    if quantized:
-        if vq:
-            kc, ks = kvq.vq_quantize_rows(k, cache.k_codebook)
-            vc, vs = kvq.vq_quantize_rows(v, cache.v_codebook)
+    with jax.named_scope("kv_write"):
+        page = pos_arr // page_size
+        blk = jnp.take_along_axis(
+            cache.page_table, jnp.minimum(page, n_pages - 1), axis=1)
+        # positions past the table extent (a padded prefill chunk can
+        # overhang max_len) go to scratch — clipping them into the last
+        # page would overwrite live K/V
+        blk = jnp.where(page < n_pages, blk, 0)
+        off = pos_arr % page_size
+        if quantized:
+            if vq:
+                kc, ks = kvq.vq_quantize_rows(k, cache.k_codebook)
+                vc, vs = kvq.vq_quantize_rows(v, cache.v_codebook)
+            else:
+                kc, ks = kvq.quantize_kv(k, kv_bits)
+                vc, vs = kvq.quantize_kv(v, kv_bits)
+            ck = cache.k.at[blk, off].set(kc)
+            cv = cache.v.at[blk, off].set(vc)
+            cks = cache.k_scale.at[blk, off].set(ks)
+            cvs = cache.v_scale.at[blk, off].set(vs)
         else:
-            kc, ks = kvq.quantize_kv(k, kv_bits)
-            vc, vs = kvq.quantize_kv(v, kv_bits)
-        ck = cache.k.at[blk, off].set(kc)
-        cv = cache.v.at[blk, off].set(vc)
-        cks = cache.k_scale.at[blk, off].set(ks)
-        cvs = cache.v_scale.at[blk, off].set(vs)
-    else:
-        ck = cache.k.at[blk, off].set(k.astype(cache.k.dtype))
-        cv = cache.v.at[blk, off].set(v.astype(cache.v.dtype))
-        cks = cvs = None
+            ck = cache.k.at[blk, off].set(k.astype(cache.k.dtype))
+            cv = cache.v.at[blk, off].set(v.astype(cache.v.dtype))
+            cks = cvs = None
     new_cache = PagedKVCache(ck, cv, cache.page_table, cks, cvs,
                              cache.k_codebook, cache.v_codebook)
 
     impl = impl or _PAGED_IMPL["impl"]
-    if S == 1 and impl in ("xla", "pallas"):
-        from repro.kernels import ops
-        _PAGED_IMPL["counts"][impl] += 1
-        o = ops.paged_attention(
-            q[:, 0], ck, cv, cache.page_table, pos_arr[:, 0],
-            k_scale=cks, v_scale=cvs,
-            k_codebook=cache.k_codebook, v_codebook=cache.v_codebook,
-            use_pallas=(impl == "pallas"),
-            interpret=jax.default_backend() != "tpu")
-        return cm.matmul(o.reshape(B, 1, -1), p["wo"]).astype(out_dtype), new_cache
-    _PAGED_IMPL["counts"]["gather"] += 1
+    with jax.named_scope("attention"):
+        if S == 1 and impl in ("xla", "pallas"):
+            from repro.kernels import ops
+            _PAGED_IMPL["counts"][impl] += 1
+            o = ops.paged_attention(
+                q[:, 0], ck, cv, cache.page_table, pos_arr[:, 0],
+                k_scale=cks, v_scale=cvs,
+                k_codebook=cache.k_codebook, v_codebook=cache.v_codebook,
+                use_pallas=(impl == "pallas"),
+                interpret=jax.default_backend() != "tpu")
+        else:
+            _PAGED_IMPL["counts"]["gather"] += 1
+            o = _gathered_attention(cache, ck, cv, cks, cvs, q, pos_arr,
+                                    vq, quantized, kv_bits)
+    with jax.named_scope("attn_out"):
+        y = cm.matmul(o.reshape(B, S, -1), p["wo"]).astype(out_dtype)
+    return y, new_cache
 
-    Sk = n_pages * page_size
+
+def _gathered_attention(cache: PagedKVCache, ck, cv, cks, cvs, q, pos_arr,
+                        vq: bool, quantized: bool, kv_bits: int):
+    """Attention over the (B, n_pages*page_size) logical view gathered
+    through the page table, dequantizing quantized pools on the fly."""
+    from repro.kernels import kv_quant as kvq
+
+    B = pos_arr.shape[0]
+    Sk = cache.page_table.shape[-1] * cache.k.shape[1]
     kg = ck[cache.page_table].reshape(B, Sk, *ck.shape[2:])
     vg = cv[cache.page_table].reshape(B, Sk, *cv.shape[2:])
     if vq:
@@ -580,8 +596,7 @@ def _paged_apply(p, cache: PagedKVCache, q, k, v, pos_arr, out_dtype,
             vg, cvs[cache.page_table].reshape(B, Sk, vg.shape[2]), kv_bits)
     # per-slot causal + length mask over logical positions
     msk = jnp.arange(Sk)[None, None, :] <= pos_arr[:, :, None]  # (B, S, Sk)
-    o = _plain_attention(q, kg, vg, msk[:, None, None])
-    return cm.matmul(o.reshape(B, S, -1), p["wo"]).astype(out_dtype), new_cache
+    return _plain_attention(q, kg, vg, msk[:, None, None])
 
 
 def cross_apply(p, cfg: ModelConfig, x, memory, *, flash_threshold=2048):

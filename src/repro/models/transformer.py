@@ -93,11 +93,12 @@ def _block_apply(p, cfg: ModelConfig, kind: str, x, *, pos, cache,
         # backward then skips re-running the flash-attention scan
         h = checkpoint_name(h, "attn_out")
         x = x + h
-        h2 = cm.rmsnorm(x, p["norm2"], cfg.norm_eps)
-        if kind == "moe":
-            f, aux = moe.apply(p["ffn"], cfg, h2)
-        else:
-            f = mlp.apply(p["ffn"], cfg, h2)
+        with jax.named_scope("mlp"):
+            h2 = cm.rmsnorm(x, p["norm2"], cfg.norm_eps)
+            if kind == "moe":
+                f, aux = moe.apply(p["ffn"], cfg, h2)
+            else:
+                f = mlp.apply(p["ffn"], cfg, h2)
         return x + f, new_kv, aux
     if kind == "mlstm":
         h, new_c = xlstm.mlstm_apply(
@@ -257,7 +258,8 @@ def forward(
         params = vql_mod.retag_fused(params, vq_matmul_impl)
     top = {k: v for k, v in params.items() if k != "layers"}
     params = {**params, **vql_mod.dequant_tree(top, cm.DTYPES[cfg.dtype])}
-    x = embed_tokens(params, cfg, tokens, extra_embeds)
+    with jax.named_scope("embed"):
+        x = embed_tokens(params, cfg, tokens, extra_embeds)
     dp = _dp_axes()
     if dp and tokens.shape[0] % _axes_size(dp) == 0:
         x = jax.lax.with_sharding_constraint(x, P(dp, None, None))
@@ -313,15 +315,17 @@ def forward(
             # §Perf iteration 1).
             def body(carry, layer_p):
                 h, cache_all, i = carry
-                layer_cache = jax.tree.map(
-                    lambda a: jax.lax.dynamic_index_in_dim(
-                        a, i, 0, keepdims=False), cache_all)
+                with jax.named_scope("layer_cache_read"):
+                    layer_cache = jax.tree.map(
+                        lambda a: jax.lax.dynamic_index_in_dim(
+                            a, i, 0, keepdims=False), cache_all)
                 h, new_c, aux = _block_apply(
                     layer_p, cfg, kind, h, pos=pos, cache=layer_cache,
                     paged_impl=paged_impl)
-                cache_all = jax.tree.map(
-                    lambda a, n: jax.lax.dynamic_update_index_in_dim(
-                        a, n.astype(a.dtype), i, 0), cache_all, new_c)
+                with jax.named_scope("layer_cache_write"):
+                    cache_all = jax.tree.map(
+                        lambda a, n: jax.lax.dynamic_update_index_in_dim(
+                            a, n.astype(a.dtype), i, 0), cache_all, new_c)
                 return (h, cache_all, i + 1), aux
 
             body_fn = jax.checkpoint(body) if remat else body
@@ -343,7 +347,8 @@ def forward(
             elif cache_is_list:
                 c_i = cache[i]
             else:
-                c_i = jax.tree.map(lambda a: a[i], cache)
+                with jax.named_scope("layer_cache_read"):
+                    c_i = jax.tree.map(lambda a: a[i], cache)
             fn = functools.partial(_block_apply, layer_p, cfg, kind,
                                    pos=pos, cache=c_i,
                                    paged_impl=paged_impl)
@@ -353,19 +358,21 @@ def forward(
             if cache_is_list:
                 new_cache.append(new_c)
             elif cache is not None:
-                new_cache = jax.tree.map(
-                    lambda a, n: a.at[i].set(n.astype(a.dtype)),
-                    new_cache, new_c)
+                with jax.named_scope("layer_cache_write"):
+                    new_cache = jax.tree.map(
+                        lambda a, n: a.at[i].set(n.astype(a.dtype)),
+                        new_cache, new_c)
             aux = aux + a
         if cache is None:
             new_cache = None
 
-    if last_only:
-        x = x[:, -1:]  # prefill: only the next-token logits are needed —
-        # avoids materializing the (B, S, V) tensor (638 TB for qwen2-72b
-        # prefill_32k before this slice; see EXPERIMENTS §Dry-run)
-    x = cm.rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    logits = unembed(params, cfg, x)
+    with jax.named_scope("head"):
+        if last_only:
+            x = x[:, -1:]  # prefill: only the next-token logits are needed
+            # — avoids materializing the (B, S, V) tensor (638 TB for
+            # qwen2-72b prefill_32k before this slice; EXPERIMENTS §Dry-run)
+        x = cm.rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        logits = unembed(params, cfg, x)
     dp = _dp_axes()
     if dp and logits.shape[0] % _axes_size(dp) == 0:
         logits = jax.lax.with_sharding_constraint(logits, P(dp, None, "model"))

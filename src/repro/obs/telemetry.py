@@ -12,8 +12,8 @@ Request lifecycle (engine-facing API)
 -------------------------------------
 ``on_enqueue`` / ``on_admit`` / ``on_token`` / ``on_preempt`` /
 ``on_finish`` / ``on_reject`` keep a ``RequestRecord`` per rid, emit the
-matching JSONL events, and feed the aggregate TTFT / inter-token-latency
-histograms. Finished records move to a drain queue:
+matching JSONL events, and feed the aggregate TTFT histogram and the
+token counter. Finished records move to a drain queue:
 ``drain_finished()`` returns-and-clears them, so a serving loop can
 stream completed-request stats without unbounded growth.
 
@@ -39,11 +39,10 @@ from repro.obs.spans import SpanTimer
 class Telemetry:
     def __init__(self, *, enabled: bool = True,
                  events_out: str | None = None,
-                 trace_dir: str | None = None,
-                 step_ref=None):
+                 trace_dir: str | None = None):
         self.enabled = enabled
         self.registry = MetricsRegistry(enabled=enabled)
-        self.spans = SpanTimer(self.registry, step_ref=step_ref)
+        self.spans = SpanTimer(self.registry)
         self.events = EventLog(events_out, enabled=enabled)
         self.trace_dir = trace_dir
         self.records: dict[int, RequestRecord] = {}
@@ -51,8 +50,6 @@ class Telemetry:
         # pre-bound aggregate instruments (hot-path: no dict lookups)
         self._ttft = self.registry.histogram("serve.ttft_s",
                                              LATENCY_BUCKETS_S)
-        self._itl = self.registry.histogram("serve.itl_s",
-                                            LATENCY_BUCKETS_S)
         self._tok = self.registry.counter("serve.tokens")
 
     # -- device profiler -----------------------------------------------------
@@ -75,7 +72,6 @@ class Telemetry:
                 rid=rid, prompt_len=prompt_len,
                 max_new_tokens=max_new_tokens)
         rec.enqueue_ts = self.events.now()
-        self.registry.counter("serve.requests_enqueued").inc()
         self.events.emit("enqueue", rid=rid, prompt_len=prompt_len,
                          max_new_tokens=max_new_tokens)
 
@@ -86,10 +82,6 @@ class Telemetry:
         rec.finish_ts = self.events.now()
         rec.finish_reason = "rejected"
         self._finished.append(rec)
-        self.registry.counter("serve.requests_rejected").inc()
-        # short alias kept alongside the legacy name: dashboards/CI key on
-        # serve.rejected; serve.requests_rejected predates it
-        self.registry.counter("serve.rejected").inc()
         self.events.emit("reject", rid=rid, error=error)
 
     def on_prefix_hit(self, rid: int, pages: int, tokens: int):
@@ -99,7 +91,6 @@ class Telemetry:
         if not self.enabled:
             return
         self.registry.counter("serve.prefix_hits").inc()
-        self.registry.counter("serve.prefix_hit_tokens").inc(tokens)
         self.events.emit("prefix_hit", rid=rid, pages=pages, tokens=tokens)
 
     def on_admit(self, rid: int, slot: int):
@@ -110,7 +101,6 @@ class Telemetry:
             rec = self.records[rid] = RequestRecord(rid=rid)
             rec.enqueue_ts = self.events.now()
         rec.admit_ts = self.events.now()
-        self.registry.counter("serve.requests_admitted").inc()
         self.events.emit("admit", rid=rid, slot=slot)
 
     def on_token(self, rid: int):
@@ -126,8 +116,6 @@ class Telemetry:
                 self._ttft.observe(now - rec.enqueue_ts)
                 self.events.emit("first_token", rid=rid,
                                  ttft_s=round(now - rec.enqueue_ts, 6))
-        elif rec.last_token_ts is not None:
-            self._itl.observe(now - rec.last_token_ts)
         rec.last_token_ts = now
         rec.tokens += 1
         self._tok.inc()
@@ -141,7 +129,6 @@ class Telemetry:
         discarded = rec.tokens
         self._tok.inc(-discarded)
         rec.on_preempt()
-        self.registry.counter("serve.preemptions").inc()
         self.events.emit("preempt", rid=rid, tokens_discarded=discarded)
 
     def on_finish(self, rid: int, reason: str):
@@ -153,7 +140,6 @@ class Telemetry:
         rec.finish_ts = self.events.now()
         rec.finish_reason = reason
         self._finished.append(rec)
-        self.registry.counter("serve.requests_finished").inc()
         self.events.emit(
             "finish", rid=rid, tokens=rec.tokens, reason=reason,
             ttft_s=rec.ttft_s, itl_mean_s=rec.itl_mean_s,

@@ -37,12 +37,14 @@ existing scatter sites and every read path dequantizes on the fly, so the
 same pool bytes hold 2-4x (scalar) to ~10x (vq2) the pages
 (``pool_bytes=`` sizes the allocator by budget instead of block count).
 
-Telemetry (PR 7): every engine owns an ``obs.Telemetry`` (pass your own
+Telemetry: every engine owns an ``obs.Telemetry`` (pass your own
 to share a registry, write a JSONL event stream, or disable it). Each
-tick feeds the metrics registry — queue depth, pool occupancy, prefill
-chunk widths, preemptions, device-upload cache hit rate — and host-side
-phases run under trace spans (``span.decode_tick/…``; the device span
-closes after the sampled-token download, so it accounts device time).
+tick feeds the metrics registry — pool occupancy and the decode batch —
+and its host-side phases run under spans (``span.admit``,
+``span.prefill``, ``span.prompt_sample``, ``span.decode_tick/…``; the
+device span closes after the sampled-token download, so it accounts
+device time). On a profiler trace the tick is the step ``serve.tick``
+and each phase is an annotation ``serve.<phase>`` (obs/spans.py).
 Per-request lifecycle records (enqueue -> admit -> first token ->
 finish) accumulate TTFT / inter-token latency and drain via
 ``drain_request_records()``; ``stats`` is a live property now —
@@ -273,25 +275,14 @@ class Engine:
                                                        kv=kv_spec))
 
         self.telemetry = telemetry if telemetry is not None else Telemetry()
-        if self.telemetry.spans._step_ref is None:
-            # StepTraceAnnotation step numbers line up with engine ticks
-            self.telemetry.spans._step_ref = lambda: self.ticks
         self._spans = self.telemetry.spans
         reg = self.telemetry.registry
-        self._m_queue = reg.gauge("serve.queue_depth")
         self._m_used = reg.gauge("serve.pool_used_blocks")
         self._m_free = reg.gauge("serve.pool_free_blocks")
         self._m_occ = reg.gauge("serve.pool_occupancy")
-        self._m_slots = reg.gauge("serve.slots_active")
         self._m_dec_batch = reg.histogram("serve.decode_batch",
                                           COUNT_BUCKETS)
-        self._m_chunk = reg.histogram("serve.prefill_chunk_tokens",
-                                      COUNT_BUCKETS)
-        self._m_dev_hit = reg.counter("serve.dev_cache_hits")
-        self._m_dev_miss = reg.counter("serve.dev_cache_misses")
         self._m_shared = reg.gauge("serve.shared_blocks")
-        self._m_cached = reg.gauge("serve.prefix_cached_blocks")
-        self._m_pfx_miss = reg.counter("serve.prefix_misses")
 
         allocator = pc.BlockAllocator(num_blocks)
         # structural recurrent-state detection: any cache leaf outside the
@@ -357,11 +348,8 @@ class Engine:
         changed since the last tick (cheap array_equal on tiny arrays)."""
         ent = self._dev_cache.get(name)
         if ent is None or not np.array_equal(ent[0], arr):
-            self._m_dev_miss.inc()
             ent = (arr.copy(), jnp.asarray(arr))
             self._dev_cache[name] = ent
-        else:
-            self._m_dev_hit.inc()
         return ent[1]
 
     @property
@@ -451,8 +439,6 @@ class Engine:
             self.telemetry.on_prefix_hit(
                 seq.req.rid, seq.shared_tokens // self.scheduler.page_size,
                 seq.shared_tokens)
-        elif self.prefix_cache is not None:
-            self._m_pfx_miss.inc()
         self._reset_slot(seq)
 
     def _reset_slot(self, seq: Sequence):
@@ -472,8 +458,16 @@ class Engine:
 
     def step(self):
         t0 = time.perf_counter()
-        for seq in self.scheduler.admit_from_queue():
-            self._admit_seq(seq)
+        with self._spans.tick(self.ticks):
+            self._tick()
+        self._wall_s += time.perf_counter() - t0
+
+    def _tick(self):
+        # admission: queue -> free slots (prefix-cache lookup, page
+        # allocation) and each admitted slot's state reset
+        with self._spans.span("admit"):
+            for seq in self.scheduler.admit_from_queue():
+                self._admit_seq(seq)
         # one chunk per prefilling slot per tick: a burst of admissions
         # drains its prompts concurrently, while a single long prompt can
         # never stall the decode cohort by more than one chunk
@@ -485,6 +479,8 @@ class Engine:
             # one table serves every chunk this tick: nothing allocates or
             # finishes between chunks of the same tick
             table = self._page_table(("prefill", "decode"))
+            # the host's dispatch of the tick's chunks: nothing in it waits
+            # for the device, whose prefill time is on the trace
             with self._spans.span("prefill"):
                 for seq in prefilling:
                     last_logits = self._prefill_chunk(seq, table)
@@ -505,19 +501,14 @@ class Engine:
                 self._emit(seq, int(t))
         self._decode_tick()
         self.ticks += 1
-        # per-tick registry feed: queue/occupancy gauges mirror the
-        # scheduler + allocator accounting exactly (fuzz-tested invariant)
+        # per-tick registry feed: occupancy gauges mirror the allocator's
+        # accounting exactly (fuzz-tested invariant)
         alloc = self.scheduler.allocator
         used = alloc.used_blocks
-        self._m_queue.set(len(self.scheduler.queue))
         self._m_used.set(used)
         self._m_free.set(alloc.free_blocks)
         self._m_occ.set(used / alloc.capacity if alloc.capacity else 0.0)
-        self._m_slots.set(len(self.scheduler.active()))
         self._m_shared.set(alloc.shared_blocks)
-        if self.prefix_cache is not None:
-            self._m_cached.set(self.prefix_cache.cached_blocks)
-        self._wall_s += time.perf_counter() - t0
 
     def _on_prompt_done(self, seq: Sequence):
         """Prefill just completed: register the prompt's full pages in
@@ -540,7 +531,6 @@ class Engine:
         """Feed the next chunk; returns the (V,) next-token logits when the
         prompt is complete, else None."""
         size, real = self.scheduler.prefill_chunk_len(seq)
-        self._m_chunk.observe(real)
         start = seq.pos
         chunk = np.zeros(size, np.int32)
         chunk[:real] = np.asarray(seq.req.prompt[start:start + real])
@@ -604,9 +594,10 @@ class Engine:
                 # point of the tick — so this span accounts device time
                 nxt, self.key, self.cache = self._decode_fn(*args)
                 nxt = np.asarray(nxt)
-        for s in decoding:
-            s.pos += 1
-            self._emit(s, int(nxt[s.slot]))
+            with self._spans.span("emit"):
+                for s in decoding:
+                    s.pos += 1
+                    self._emit(s, int(nxt[s.slot]))
         self._decode_ticks += 1
 
     def _on_preempt(self, victim: Sequence):

@@ -74,14 +74,17 @@ def make_paged_decode(model: Model, axes, paged_impl: str = "gather",
         their pre-tick values (slots still mid-prefill); key is the
         engine PRNG key (split in-graph, new key returned); temps (B,)
         per-slot temperatures (<= 0 greedy)."""
-        cache = pc.push_page_table(cache, table)
+        with jax.named_scope("push_page_table"):
+            cache = pc.push_page_table(cache, table)
         logits, new_cache, _ = model.forward(
             params, {"tokens": tokens}, cache=cache, pos=pos,
             paged_impl=paged_impl, vq_matmul_impl=vq_impl)
-        key, sub = jax.random.split(key)
-        nxt = sampling.sample(sub, logits[:, -1], temperature=temps)
-        return nxt, key, pc.restore_masked(cache, new_cache, axes,
-                                           keep_mask)
+        with jax.named_scope("sample"):
+            key, sub = jax.random.split(key)
+            nxt = sampling.sample(sub, logits[:, -1], temperature=temps)
+        with jax.named_scope("restore_masked"):
+            new_cache = pc.restore_masked(cache, new_cache, axes, keep_mask)
+        return nxt, key, new_cache
 
     return decode
 
@@ -94,8 +97,10 @@ def make_slot_prefill(model: Model, axes, vq_impl: str | None = None):
     from repro.serve import paged_cache as pc
 
     def chunk(params, tokens, cache, slot, start, last_idx, table):
-        cache = pc.push_page_table(cache, table)
-        view = pc.slot_view_dyn(cache, axes, slot)
+        with jax.named_scope("push_page_table"):
+            cache = pc.push_page_table(cache, table)
+        with jax.named_scope("slot_view"):
+            view = pc.slot_view_dyn(cache, axes, slot)
         # prefill is pinned to the gather read path — including width-1
         # tail chunks, which would otherwise satisfy the fused path's
         # S == 1 shape test
@@ -106,8 +111,11 @@ def make_slot_prefill(model: Model, axes, vq_impl: str | None = None):
         # only the last *real* token's logits ever get sampled (chunks may
         # be padded up to their power-of-two bucket) — returning (V,)
         # instead of (1, C, V) keeps the host transfer flat
-        last = jax.lax.dynamic_index_in_dim(logits[0], last_idx, 0,
-                                            keepdims=False)
-        return last, pc.slot_merge_dyn(cache, new_view, axes, slot)
+        with jax.named_scope("head"):
+            last = jax.lax.dynamic_index_in_dim(logits[0], last_idx, 0,
+                                                keepdims=False)
+        with jax.named_scope("slot_merge"):
+            cache = pc.slot_merge_dyn(cache, new_view, axes, slot)
+        return last, cache
 
     return chunk

@@ -134,9 +134,55 @@ class TestSpans:
             with spans.span("a/b"):
                 pass
 
-    def test_timed_helper_returns_value(self):
-        spans = SpanTimer(MetricsRegistry())
-        assert spans.timed("f", lambda x: x + 1, 41) == 42
+    def test_tick_is_a_step_and_phases_are_annotations(self, monkeypatch):
+        """While a trace records, a tick is one StepTraceAnnotation named
+        ``serve.tick`` and each span inside it a plain TraceAnnotation
+        named ``serve.<path>``; the registry keeps ``span.<path>`` and
+        nothing for the tick; outside a tick a span is annotated by its
+        bare path."""
+        import jax
+
+        seen = []
+
+        class Ann:
+            def __init__(self, kind, name, **kw):
+                self.entry = (kind, name, kw)
+
+            def __enter__(self):
+                seen.append(("enter",) + self.entry)
+
+            def __exit__(self, *exc):
+                seen.append(("exit", self.entry[1]))
+
+        monkeypatch.setattr(jax.profiler, "StepTraceAnnotation",
+                            lambda name, **kw: Ann("step", name, **kw))
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                            lambda name, **kw: Ann("ann", name, **kw))
+        reg = MetricsRegistry()
+        spans = SpanTimer(reg)
+        with spans.tick(3):
+            with spans.span("admit"):
+                pass
+        assert seen == []                    # no trace: no annotation
+        spans._tracing = True
+        with spans.tick(4):
+            with spans.span("decode_tick"):
+                with spans.span("emit"):
+                    pass
+        with spans.span("quant"):
+            pass
+        assert seen == [
+            ("enter", "step", "serve.tick", {"step_num": 4}),
+            ("enter", "ann", "serve.decode_tick", {}),
+            ("enter", "ann", "serve.decode_tick/emit", {}),
+            ("exit", "serve.decode_tick/emit"),
+            ("exit", "serve.decode_tick"),
+            ("exit", "serve.tick"),
+            ("enter", "ann", "quant", {}),
+            ("exit", "quant")]
+        assert set(reg.snapshot()) == {"span.admit", "span.decode_tick",
+                                       "span.decode_tick/emit",
+                                       "span.quant"}
 
 
 class TestEvents:
