@@ -64,6 +64,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.paged_attention import pages_per_block
 from repro.models.attention import KVQuantSpec, PagedKVCache, PagedLayout
 from repro.models.model_zoo import Model
 from repro.obs import COUNT_BUCKETS, Telemetry
@@ -71,6 +72,8 @@ from repro.serve import paged_cache as pc
 from repro.serve import sampling
 from repro.serve.scheduler import CapacityError, Scheduler, Sequence
 from repro.serve.serve_step import make_paged_decode, make_slot_prefill
+
+SHARE_BUCKETS = tuple(i / 10 for i in range(1, 11))
 
 
 @dataclasses.dataclass
@@ -282,6 +285,16 @@ class Engine:
         self._m_occ = reg.gauge("serve.pool_occupancy")
         self._m_dec_batch = reg.histogram("serve.decode_batch",
                                           COUNT_BUCKETS)
+        # share of the paged-attention kernel's page blocks a decode tick
+        # reads (kernels/paged_attention.py: a slot reads its blocks up to
+        # the one holding pos, an idle slot its first)
+        self._m_live_blocks = reg.histogram("serve.attn_live_block_share",
+                                            SHARE_BUCKETS)
+        cfg = model.cfg
+        ppb = pages_per_block(cfg.n_heads, cfg.hd, page_size, cfg.n_kv_heads,
+                              n_pages, dtype, kv_spec.fmt)
+        self._block_rows = ppb * page_size
+        self._n_attn_blocks = -(-n_pages // ppb)
         self._m_shared = reg.gauge("serve.shared_blocks")
 
         allocator = pc.BlockAllocator(num_blocks)
@@ -583,6 +596,9 @@ class Engine:
                         temps[s.slot] = s.req.temperature
                     else:
                         keep[s.slot] = True
+                self._m_live_blocks.observe(
+                    float(np.sum(pos // self._block_rows + 1))
+                    / (self.max_batch * self._n_attn_blocks))
                 toks = jnp.asarray(self.last_tok[:, None], jnp.int32)
                 args = (self.params, toks, self.cache, jnp.asarray(pos),
                         self._dev("table_dec",

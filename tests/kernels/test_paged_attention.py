@@ -17,18 +17,29 @@ the staleness invariants additionally poison the *scales* of masked
 rows: a stale scale must be discarded exactly like a stale key. The
 quantized oracles are also pinned bitwise against the fp oracle
 evaluated on the kv_quant-decoded pool, so every read path shares one
-decode expression down to the last ulp.
+decode expression down to the last ulp. At serving widths, slots on the
+edges of the kernel's page blocks read as the oracle does while pages
+past their positions and scratch block 0 hold NaN or huge values.
+
+The kernel runs in the TPU interpreter: its DMAs and semaphores are
+simulated and VMEM no copy reached reads as NaN. ``ops.paged_attention``
+(the engine's dispatch) runs Pallas's HLO interpreter on the CPU.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import kv_quant as kvq
 from repro.kernels import ops, ref
-from repro.kernels.paged_attention import paged_attention_tpu
+from repro.kernels.paged_attention import pages_per_block, \
+    paged_attention_tpu
 
 pytestmark = pytest.mark.kernels
+
+# the TPU interpreter: DMAs and semaphores simulated, VMEM starts as NaN
+INTERPRET = pltpu.InterpretParams()
 
 BITS = [16, 8, 4, kvq.VQ_BITS]
 
@@ -70,7 +81,7 @@ def assert_matches_oracle(q, kp, vp, table, pos, ksc=None, vsc=None,
                           kcb=None, vcb=None, tol=2e-5):
     got = paged_attention_tpu(q, kp, vp, table, pos, k_scale=ksc,
                               v_scale=vsc, k_codebook=kcb, v_codebook=vcb,
-                              interpret=True)
+                              interpret=INTERPRET)
     want = ref.paged_attention_ref(q, kp, vp, table, pos, k_scale=ksc,
                                    v_scale=vsc, k_codebook=kcb,
                                    v_codebook=vcb)
@@ -115,7 +126,8 @@ class TestDifferentialSweep:
         q, kp, vp, table, pos, _, _, _, _ = make_case(
             1, B=2, H=8, KV=4, hd=32, page_size=8, n_pages=4,
             num_blocks=12, dtype=dtype)
-        got = paged_attention_tpu(q, kp, vp, table, pos, interpret=True)
+        got = paged_attention_tpu(q, kp, vp, table, pos,
+                                  interpret=INTERPRET)
         assert got.dtype == dtype
         assert_matches_oracle(q, kp, vp, table, pos, tol=tol)
 
@@ -191,7 +203,7 @@ class TestMaskingInvariants:
         assert int(jnp.min(table[:, 0])) > 0  # live pages avoid scratch
         base = paged_attention_tpu(q, kp, vp, table, pos, k_scale=ksc,
                                    v_scale=vsc, k_codebook=kcb,
-                                   v_codebook=vcb, interpret=True)
+                                   v_codebook=vcb, interpret=INTERPRET)
         if bits == 16:
             kp2 = kp.at[0].set(1e4)
             vp2 = vp.at[0].set(-1e4)
@@ -204,7 +216,7 @@ class TestMaskingInvariants:
         poisoned = paged_attention_tpu(q, kp2, vp2, table, pos,
                                        k_scale=ksc2, v_scale=vsc2,
                                        k_codebook=kcb, v_codebook=vcb,
-                                       interpret=True)
+                                       interpret=INTERPRET)
         np.testing.assert_allclose(np.asarray(base), np.asarray(poisoned),
                                    rtol=1e-6, atol=1e-6)
         assert_matches_oracle(q, kp2, vp2, table, pos, ksc2, vsc2, kcb, vcb)
@@ -221,7 +233,7 @@ class TestMaskingInvariants:
         assert_matches_oracle(q, kp, vp, table, pos, ksc, vsc, kcb, vcb)
         out = paged_attention_tpu(q, kp, vp, table, pos, k_scale=ksc,
                                   v_scale=vsc, k_codebook=kcb,
-                                  v_codebook=vcb, interpret=True)
+                                  v_codebook=vcb, interpret=INTERPRET)
         assert bool(jnp.all(jnp.isfinite(out)))
 
     @pytest.mark.parametrize("bits", BITS)
@@ -239,7 +251,7 @@ class TestMaskingInvariants:
         off = 11 % page_size
         base = paged_attention_tpu(q, kp, vp, table, pos, k_scale=ksc,
                                    v_scale=vsc, k_codebook=kcb,
-                                   v_codebook=vcb, interpret=True)
+                                   v_codebook=vcb, interpret=INTERPRET)
         kmag, vmag = (7e3, -7e3) if bits == 16 else (127, -127)
         # stale tail: rows (off+1..) of the slot's own last page
         kp2 = kp.at[last_blk, off + 1:].set(kmag)
@@ -259,10 +271,87 @@ class TestMaskingInvariants:
         poisoned = paged_attention_tpu(q, kp2, vp2, table, pos,
                                        k_scale=ksc2, v_scale=vsc2,
                                        k_codebook=kcb, v_codebook=vcb,
-                                       interpret=True)
+                                       interpret=INTERPRET)
         np.testing.assert_allclose(np.asarray(base), np.asarray(poisoned),
                                    rtol=1e-6, atol=1e-6)
         assert_matches_oracle(q, kp2, vp2, table, pos, ksc2, vsc2, kcb, vcb)
+
+
+class TestPageBlocks:
+    """The kernel walks each slot's pages in blocks of pages_per_block and
+    copies only live pages. Cases at serving widths (16 query heads over 8
+    kv heads of 128, pages of 16 rows, 20 pages a slot: not a multiple of
+    the block) put one slot inside its first page — first, so that most of
+    its block is VMEM no page was ever copied to — one on the last row of
+    its first block, one on the first row of the next, an idle slot at
+    pos 0 on an all-scratch table, and one at full depth. Poison fills scratch
+    block 0 and blocks mapped past each live slot's last page — stale
+    blocks of an earlier owner — with NaN or huge values (codes at their
+    extremes, scales poisoned, for the packed formats). Live slots must
+    read as the oracle does on the clean pool; the idle slot, which
+    attends scratch row 0, as the oracle on the poisoned pool."""
+
+    FORMATS = [(16, jnp.float32, 2e-5), (16, jnp.bfloat16, 4e-2),
+               (8, jnp.float32, 2e-5), (4, jnp.float32, 2e-5),
+               (kvq.VQ_BITS, jnp.float32, 2e-5)]
+
+    @pytest.mark.parametrize("poison", ["none", "huge", "nan"])
+    @pytest.mark.parametrize("bits,dtype,tol", FORMATS,
+                             ids=["f32", "bf16", "8", "4", "vq2"])
+    def test_block_edges_match_oracle(self, bits, dtype, tol, poison):
+        H, KV, hd, page_size, n_pages = 16, 8, 128, 16, 20
+        pool_dtype = dtype if bits == 16 else jnp.int8
+        ppb = pages_per_block(H, hd, page_size, KV, n_pages, pool_dtype,
+                              bits)
+        assert 1 < ppb < n_pages and n_pages % ppb
+        rows, full = ppb * page_size, n_pages * page_size
+        pos = [5, rows - 1, rows, 0, full - 1]
+        B, idle = len(pos), 3
+        num_blocks = B * n_pages + 8
+        q, kp, vp, table, pos, ksc, vsc, kcb, vcb = make_case(
+            11, B=B, H=H, KV=KV, hd=hd, page_size=page_size,
+            n_pages=n_pages, num_blocks=num_blocks, pos=pos, dtype=dtype,
+            bits=bits)
+        table = np.array(table)
+        table[idle] = 0
+        # pages past pos of the live slots map to stale blocks (the rest
+        # stay on scratch block 0)
+        spare = sorted(set(range(1, num_blocks)) - set(table.ravel()))
+        for b in (0, 1, 2):
+            dead = int(pos[b]) // page_size + 1
+            table[b, dead:dead + 2] = spare[:2]
+            spare = spare[2:]
+        table = jnp.asarray(table)
+        stale = [0] + [int(x) for b in (0, 1, 2)
+                       for x in table[b, int(pos[b]) // page_size + 1:]
+                       if int(x)]
+        clean = (kp, vp, ksc, vsc)
+        if poison != "none":
+            bad = jnp.nan if poison == "nan" else 1e4
+            if bits == 16:
+                kp = kp.at[jnp.asarray(stale)].set(bad)
+                vp = vp.at[jnp.asarray(stale)].set(-bad)
+            else:
+                code = -1 if bits == kvq.VQ_BITS else 127
+                kp = kp.at[jnp.asarray(stale)].set(code)
+                vp = vp.at[jnp.asarray(stale)].set(code)
+                ksc = ksc.at[jnp.asarray(stale)].set(bad)
+                vsc = vsc.at[jnp.asarray(stale)].set(bad)
+        got = np.asarray(paged_attention_tpu(
+            q, kp, vp, table, pos, k_scale=ksc, v_scale=vsc,
+            k_codebook=kcb, v_codebook=vcb, interpret=INTERPRET),
+            np.float32)
+        want_clean = np.asarray(ref.paged_attention_ref(
+            q, clean[0], clean[1], table, pos, k_scale=clean[2],
+            v_scale=clean[3], k_codebook=kcb, v_codebook=vcb), np.float32)
+        want = np.asarray(ref.paged_attention_ref(
+            q, kp, vp, table, pos, k_scale=ksc, v_scale=vsc,
+            k_codebook=kcb, v_codebook=vcb), np.float32)
+        live = [b for b in range(B) if b != idle]
+        np.testing.assert_allclose(got[live], want_clean[live],
+                                   rtol=tol, atol=tol)
+        np.testing.assert_allclose(got[idle], want[idle], rtol=tol, atol=tol)
+        assert np.isfinite(got[live]).all()
 
 
 class TestVQPages:
@@ -306,7 +395,7 @@ class TestVQPages:
         vcb = vcb.at[:, 15].set(-1e4)
         base = paged_attention_tpu(q, kp, vp, table, pos, k_scale=ksc,
                                    v_scale=vsc, k_codebook=kcb,
-                                   v_codebook=vcb, interpret=True)
+                                   v_codebook=vcb, interpret=INTERPRET)
         # poison the scratch block's codes toward the huge entry
         kp2 = kp.at[0].set(-1)  # 0xFF -> nibbles (15, 15)
         vp2 = vp.at[0].set(-1)
@@ -315,7 +404,7 @@ class TestVQPages:
         poisoned = paged_attention_tpu(q, kp2, vp2, table, pos,
                                        k_scale=ksc2, v_scale=vsc2,
                                        k_codebook=kcb, v_codebook=vcb,
-                                       interpret=True)
+                                       interpret=INTERPRET)
         np.testing.assert_allclose(np.asarray(base), np.asarray(poisoned),
                                    rtol=1e-6, atol=1e-6)
         assert_matches_oracle(q, kp2, vp2, table, pos, ksc2, vsc2, kcb, vcb)

@@ -3,11 +3,12 @@
 The interpret-mode suites check the kernels' math; only the TPU compiler
 checks that their block shapes tile, that their bodies lower to Mosaic and
 that they fit the chip's scoped VMEM. Each case lowers one kernel at
-qwen3-1.7b's serving widths (B=8, H=16, KV=8, hd=128, page_size=16; the
-2.25bpv_2d layout of its three weight shapes) against a ``v5e:2x2``
-topology description and compiles it for the first chip. Nothing runs, so
-this needs no TPU; where the topology cannot be described the fixture
-skips every case.
+qwen3-1.7b's serving widths (H=16, KV=8, hd=128, page_size=16; the paged
+kernel at the benchmark cell's 64 slots of 64 pages over a 1792-block
+pool; the 2.25bpv_2d layout of its three weight shapes) against a
+``v5e:2x2`` topology description and compiles it for the first chip.
+Nothing runs, so this needs no TPU; where the topology cannot be described
+the fixture skips every case.
 """
 import os
 
@@ -22,7 +23,8 @@ from repro.kernels import kv_quant as kvq
 
 pytestmark = pytest.mark.kernels
 
-B, H, KV, HD, PAGE, N_PAGES = 8, 16, 8, 128, 16, 32
+H, KV, HD, PAGE = 16, 8, 128, 16
+B, N_PAGES, N_BLOCKS = 64, 64, 1792       # the serving cell's pool
 
 
 @pytest.fixture(scope="module")
@@ -70,13 +72,10 @@ def _s(shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-@pytest.mark.parametrize("bits", [16, 8, 4, kvq.VQ_BITS])
-def test_paged_attention_compiles(compile_tpu, bits):
-    """Decode attention over the engine's pool: bf16 queries; an f32 pool
-    (what the engine allocates) or int8 code pages + f32 scales."""
+def _compile_paged(compile_tpu, bits, n_heads):
     from repro.kernels.paged_attention import paged_attention_tpu
 
-    nb = B * N_PAGES + 1
+    nb = N_BLOCKS
     if bits == 16:
         pool = _s((nb, PAGE, KV, HD), jnp.float32)
     else:
@@ -89,8 +88,31 @@ def test_paged_attention_compiles(compile_tpu, bits):
         kw["k_codebook"] = kw["v_codebook"] = _s((KV, kvq.VQ_K, kvq.VQ_D),
                                                  jnp.float32)
     compile_tpu(lambda *a, **k: paged_attention_tpu(*a, **k),
-                _s((B, H, HD), jnp.bfloat16), pool, pool,
+                _s((B, n_heads, HD), jnp.bfloat16), pool, pool,
                 _s((B, N_PAGES), jnp.int32), _s((B,), jnp.int32), **kw)
+
+
+@pytest.mark.parametrize("bits", [16, 8, 4, kvq.VQ_BITS])
+def test_paged_attention_compiles(compile_tpu, bits):
+    """Decode attention over the engine's pool: bf16 queries; an f32 pool
+    (what the engine allocates) or int8 code pages + f32 scales. The
+    kernel derives its pages per block from these shapes, and the block
+    must fit the chip's scoped VMEM."""
+    _compile_paged(compile_tpu, bits, H)
+
+
+def test_paged_attention_block_of_f32_pool():
+    """An f32 pool at qwen3-1.7b's widths: 16 pages per grid step, 1 MiB
+    per K block."""
+    from repro.kernels.paged_attention import pages_per_block
+
+    assert pages_per_block(H, HD, PAGE, KV, N_PAGES, jnp.float32, 16) == 16
+
+
+@pytest.mark.parametrize("bits", [16, kvq.VQ_BITS])
+def test_paged_attention_compiles_yi34b_heads(compile_tpu, bits):
+    """Yi-34B's 56 query heads over 8 kv heads: the widest score rows."""
+    _compile_paged(compile_tpu, bits, 56)
 
 
 @pytest.mark.parametrize("n,k", [(6144, 2048), (2048, 6144), (2048, 2048)])

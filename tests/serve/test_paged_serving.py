@@ -820,3 +820,49 @@ class TestPreemption:
         for a, b in zip(ref, out):
             assert a.out_tokens == b.out_tokens
         assert tight.stats["tokens"] == sum(len(r.out_tokens) for r in out)
+
+
+class TestAttnLiveBlockShare:
+    def test_share_follows_decode_positions(self):
+        """serve.attn_live_block_share: every decode tick observes the
+        share of the paged kernel's page blocks its positions reach — a
+        slot its blocks up to the one holding pos, an idle or prefilling
+        slot (pos 0) its first — computed from the positions the tick
+        hands the device and the kernel's own pages_per_block."""
+        from repro.kernels.paged_attention import pages_per_block
+
+        cfg = SMOKE["llama2-7b"].scaled(
+            dtype="float32", n_layers=1, d_model=256, n_heads=2,
+            n_kv_heads=2, head_dim=128, d_ff=256, vocab_size=256,
+            max_seq_len=512)
+        model = model_zoo.build(cfg)
+        params = model.init_params(jax.random.PRNGKey(0))
+        page_size, max_len, max_batch = 16, 512, 3
+        n_pages = max_len // page_size
+        ppb = pages_per_block(cfg.n_heads, cfg.hd, page_size,
+                              cfg.n_kv_heads, n_pages, jnp.float32, 16)
+        assert ppb < n_pages   # more than one block per slot
+        rows, n_blocks = ppb * page_size, -(-n_pages // ppb)
+        eng = Engine(model, params, max_batch=max_batch, max_len=max_len,
+                     page_size=page_size)
+        seen = []
+        decode = eng._decode_fn
+
+        def spy(*args):
+            seen.append(np.asarray(args[3]))
+            return decode(*args)
+
+        eng._decode_fn = spy
+        rng = np.random.RandomState(0)
+        # one slot decodes across a block edge, one stays in its first
+        # block, the third slot stays idle
+        reqs = greedy_reqs([rng.randint(0, 255, size=rows - 4),
+                            rng.randint(0, 255, size=20)], n=8)
+        eng.run(reqs)
+        want = [float(np.sum(p // rows + 1)) / (max_batch * n_blocks)
+                for p in seen]
+        h = eng.telemetry.registry.histogram("serve.attn_live_block_share")
+        assert h.count == len(want) == eng.stats["decode_ticks"]
+        np.testing.assert_allclose([h.sum, h.min, h.max],
+                                   [sum(want), min(want), max(want)])
+        assert len(set(want)) > 1   # the edge was crossed
