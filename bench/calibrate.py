@@ -29,7 +29,7 @@ from bench import check, manifest, run, serve, traffic  # noqa: E402
 
 def one_seed(cell, seed: int, seconds: float, counter) -> dict:
     spec, mix = cell.spec, cell.mix
-    engine = serve.build_engine(spec, cell.config, mix, seed)
+    engine = serve.build_engine(cell.arch, spec, cell.config, mix, seed)
     serve.warm_up(engine, spec, mix, seconds, seed)
     arrivals = traffic.schedule(mix, seed, seconds, spec.vocab)
     window = serve.drive(engine, arrivals, seconds, counter)
@@ -38,8 +38,8 @@ def one_seed(cell, seed: int, seconds: float, counter) -> dict:
     del engine
     gc.collect()
     limits = check.load_limits(cell.name)
-    ok, shown, info = serve.correctness(spec, window, seed, limits,
-                                        control=True)
+    ok, shown, info = serve.correctness(cell.arch, spec, window, seed,
+                                        limits, control=True)
     return {"seed": seed, "correct": ok, **{k: v["value"]
                                             for k, v in shown.items()},
             **info, **e2e, "compiles_in_window": window.compiles,

@@ -5,7 +5,7 @@ import dataclasses
 import json
 from pathlib import Path
 
-from bench import spec, traffic
+from bench import arch, spec, traffic
 
 ROOT = Path(__file__).resolve().parent.parent
 MANIFEST = ROOT / "BENCHMARK.json"
@@ -15,14 +15,15 @@ MANIFEST = ROOT / "BENCHMARK.json"
 class Cell:
     name: str
     chips: int
+    arch: object          # the configuration's module of bench/arch/
     config: dict          # the configuration file's contents
     mix: dict             # the traffic file's contents
     end_to_end: list      # metric entries this cell reports (--trace 0)
     per_layer: list       # metric entries this cell reports (--trace 1)
 
     @property
-    def spec(self) -> spec.ModelSpec:
-        return spec.ModelSpec.from_config(self.config)
+    def spec(self):
+        return self.arch.spec(self.config)
 
 
 def _applies(metric: dict, cell: str, e2e_names: set) -> bool:
@@ -41,7 +42,7 @@ def load_cell(name: str, manifest: Path = MANIFEST) -> Cell:
     e2e = [e for e in m["end_to_end"] if _applies(e, name, set())]
     names = {e["name"] for e in e2e}
     per = [p for p in m["per_layer"] if _applies(p, name, names)]
-    return Cell(name=name, chips=w["chips"],
-                config=spec.load(Path(manifest).parent / conf["file"]),
-                mix=traffic.load(w["traffic"]), end_to_end=e2e,
-                per_layer=per)
+    config = spec.load(Path(manifest).parent / conf["file"])
+    return Cell(name=name, chips=w["chips"], arch=arch.load(config),
+                config=config, mix=traffic.load(w["traffic"]),
+                end_to_end=e2e, per_layer=per)

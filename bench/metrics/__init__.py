@@ -3,9 +3,10 @@
 Each reader defines ``read(ctx) -> float | None`` and returns None where it
 finds nothing to read (the metric is then left out of the result line);
 a share of a roofline or of a peak is never reported as 0 for want of
-data. ``ctx`` is a ``Context``: the cell, its model sizes, the window's
-record (ticks, requests, registry deltas of the traced part) and the
-reduced device trace.
+data. ``ctx`` is a ``Context``: the cell's architecture module
+(``bench/arch/``, which counts the model's work) and model sizes, the
+window's record (ticks, requests, registry deltas of the traced part) and
+the reduced device trace.
 """
 from __future__ import annotations
 
@@ -18,7 +19,8 @@ METRICS_DIR = Path(__file__).resolve().parent
 
 @dataclasses.dataclass
 class Context:
-    spec: object          # bench.spec.ModelSpec
+    arch: object          # the configuration's module of bench/arch/
+    spec: object          # that module's spec of the configuration
     window: object        # bench.serve.Window
     trace: object         # bench.trace.Summary
     device_kind: str

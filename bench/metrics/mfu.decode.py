@@ -1,8 +1,9 @@
 """Whole decode step's share of the chip's bf16 peak: the model FLOPs of
-every token decoded in the traced steps (layers, attention over its
-context, the head), over the decode programs' device time times the
-peak. Moves tokens_per_s, beside the kernels' rooflines."""
-from bench.work import model, peaks
+every token decoded in the traced steps (the architecture's
+``decode_flops``: layers, attention over its context, the head), over the
+decode programs' device time times the peak. Moves tokens_per_s, beside
+the kernels' rooflines."""
+from bench.work import peaks
 
 PROGRAM = "decode"
 
@@ -15,5 +16,5 @@ def read(ctx):
         return None
     n = sum(len(t.decode_context) for t in ticks)
     ctx_rows = sum(sum(t.decode_context) for t in ticks)
-    f = model.flops(ctx.spec, n, ctx_rows, n_logits=n)
+    f = ctx.arch.decode_flops(ctx.spec, n, ctx_rows, n_logits=n)
     return 100.0 * f / (spent * peaks(ctx.device_kind)["bf16_flops_per_s"])

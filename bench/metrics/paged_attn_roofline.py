@@ -2,8 +2,9 @@
 
 Required work (bench/work/paged_attention.py): for every live slot of a
 traced decode step, its K and V rows 0..pos in the float32 pool, q and the
-output; per layer. The least time of every traced step at the chip's
-peaks, over the kernel's time inside the decode programs."""
+output; in each layer that attends through the pool (the architecture's
+``paged_attention_layers``). The least time of every traced step at the
+chip's peaks, over the kernel's time inside the decode programs."""
 from bench.work import paged_attention as pa
 from bench.work import roofline
 
@@ -11,13 +12,13 @@ PROGRAM, KERNEL = "decode", "paged_attention_tpu"
 
 
 def read(ctx):
-    spec = ctx.spec
+    n_layers, n_heads, n_kv, hd = ctx.arch.paged_attention_layers(ctx.spec)
     ticks = [t for t in ctx.traced_ticks if t.decode_context]
     spent = ctx.trace.kernel_seconds(KERNEL, PROGRAM)
-    if not ticks or spent <= 0 or \
+    if not n_layers or not ticks or spent <= 0 or \
             len(ticks) != ctx.trace.program_count(PROGRAM):
         return None
-    least = sum(roofline(pa.work(t.decode_context, spec.n_heads, spec.n_kv,
-                                 spec.hd), ctx.device_kind)[0]
+    least = sum(roofline(pa.work(t.decode_context, n_heads, n_kv, hd),
+                         ctx.device_kind)[0]
                 for t in ticks)
-    return 100.0 * least * spec.n_layers / spent
+    return 100.0 * least * n_layers / spent

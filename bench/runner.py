@@ -36,7 +36,7 @@ def run(cell, seed: int, seconds: float, with_trace: bool, devs,
 def run_serving(cell, seed, seconds, with_trace, devs, process_start):
     spec, mix = cell.spec, cell.mix
     counter = serve.CompileCounter()
-    engine = serve.build_engine(spec, cell.config, mix, seed)
+    engine = serve.build_engine(cell.arch, spec, cell.config, mix, seed)
     impls = (engine.vq_matmul_impl, engine.paged_attn_impl)
     log(f"engine: {spec.name} layers={spec.n_layers} impls={impls} "
         f"max_batch={engine.max_batch} max_len={engine.max_len}")
@@ -75,7 +75,7 @@ def run_serving(cell, seed, seconds, with_trace, devs, process_start):
     if with_trace:
         summary = trace_mod.load(prof_dir)
         shutil.rmtree(prof_dir, ignore_errors=True)
-        ctx = metrics.Context(spec=spec, window=window,
+        ctx = metrics.Context(arch=cell.arch, spec=spec, window=window,
                               trace=summary, device_kind=devs[0].device_kind,
                               mix=mix)
         per_layer = metrics.read_all(cell.per_layer, ctx)
@@ -89,7 +89,7 @@ def run_serving(cell, seed, seconds, with_trace, devs, process_start):
     del engine
     gc.collect()
     limits = check.load_limits(cell.name)
-    ok, shown, info = serve.correctness(spec, window, seed, limits)
+    ok, shown, info = serve.correctness(cell.arch, spec, window, seed, limits)
     log(f"check: {info}")
     # nothing may compile inside the measured window
     shown["compiles_in_window"] = {"value": window.compiles, "limit": 0}

@@ -28,8 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import check, traffic, weights
-from bench.spec import ModelSpec
+from bench import check, traffic
 
 
 class CompileCounter:
@@ -80,16 +79,18 @@ class Window:
     registry_delta: dict | None = None
 
 
-def build_engine(spec: ModelSpec, cfg: dict, mix: dict, seed: int):
+def build_engine(arch, spec, cfg: dict, mix: dict, seed: int):
+    """The program's engine over ``arch``'s seeded payload, with the
+    configuration's options and the mix's slots and pool."""
     from repro.models import model_zoo
     from repro.serve.engine import Engine
 
-    raw = weights.raw_payload(spec, seed)
-    params = weights.program_params(spec, raw)
+    raw = arch.raw_payload(spec, seed)
+    params = arch.program_params(spec, raw)
     del raw
     eng_cfg = cfg["engine"]
     engine = Engine(
-        model_zoo.build(spec.program_config()), params,
+        model_zoo.build(arch.program_config(spec)), params,
         max_batch=mix["engine"]["max_batch"],
         max_len=mix["engine"]["max_len"],
         num_blocks=mix["engine"].get("num_blocks"),
@@ -124,7 +125,7 @@ def chunk_widths(scheduler, prompt_lengths) -> dict[int, int]:
     return out
 
 
-def warm_up(engine, spec: ModelSpec, mix: dict, seconds: float, seed: int):
+def warm_up(engine, spec, mix: dict, seconds: float, seed: int):
     """Compile every shape the window will use, and no other: a prompt of
     the mix for each prefill chunk width the scheduler feeds its prompts
     in, with the decode step after it; then the prompt sampler as a tick
@@ -309,13 +310,12 @@ def end_to_end(window: Window) -> dict:
     }
 
 
-def correctness(spec: ModelSpec, window: Window, seed: int, limits: dict,
+def correctness(arch, spec, window: Window, seed: int, limits: dict,
                 control: bool = False) -> tuple[bool, dict, dict]:
-    """Reference check of a seeded sample of the finished requests. Call
-    with the program's state freed. ``control`` also reads the float8
-    control over the same prompts and served tokens."""
-    from bench import reference
-
+    """Reference check of a seeded sample of the finished requests, by
+    ``arch``'s plain reference over its seeded payload. Call with the
+    program's state freed. ``control`` also reads the float8 control over
+    the same prompts and served tokens."""
     rng = np.random.default_rng(int(seed) ^ 0xC4EC)
     finished = [s.req for s in window.sent
                 if s.req.done and s.req.error is None]
@@ -328,15 +328,15 @@ def correctness(spec: ModelSpec, window: Window, seed: int, limits: dict,
             "sampled_tokens": int(sum(len(r.out_tokens) for r in sample))}
     if sample:
         gc.collect()
-        raw = weights.raw_payload(spec, seed)
+        raw = arch.raw_payload(spec, seed)
         seqs, pos, served = check.reference_inputs(sample)
-        ref = reference.logits_at(raw, spec, seqs, pos, precision="f32")
+        ref = arch.logits_at(raw, spec, seqs, pos, precision="f32")
         gaps = np.concatenate([check.served_gaps(r, s, spec.vocab)
                                for r, s in zip(ref, served)])
         readings["served_logit_gap"] = float(gaps.max())
         info["gap_median"] = float(np.median(gaps))
         if control:
-            ctl = reference.logits_at(raw, spec, seqs, pos, precision="fp8")
+            ctl = arch.logits_at(raw, spec, seqs, pos, precision="fp8")
             cg = np.concatenate([check.control_gaps(r, c, spec.vocab)
                                  for r, c in zip(ref, ctl)])
             info["control_served_logit_gap"] = float(cg.max())

@@ -1,19 +1,17 @@
-"""A benchmark configuration file, read into the sizes the harness needs.
+"""A benchmark configuration file, and the served weight format it names.
 
 Configuration files (``bench/configs/<name>.json``) keep the published
 ``config.json`` keys at their top level, as the source states them, beside
 the benchmark's own groups: ``weights`` (the served format), ``engine``
-(the serving engine's options), ``reduced`` and ``assumed``.
+(the serving engine's options), ``reduced`` and ``assumed``. The sizes a
+cell needs are read from them by the configuration's architecture module
+(``bench/arch/``).
 """
 from __future__ import annotations
 
 import dataclasses
 import json
 from pathlib import Path
-
-# qk-norm (a per-head RMSNorm of q and k before RoPE) is part of these
-# published architectures; the keys of their config.json do not say so
-QK_NORM_MODEL_TYPES = ("qwen3",)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -43,74 +41,16 @@ class VQFormat:
         return cg, rg
 
 
-@dataclasses.dataclass(frozen=True)
-class ModelSpec:
-    name: str
-    d: int
-    n_layers: int
-    n_heads: int
-    n_kv: int
-    hd: int
-    d_ff: int
-    vocab: int
-    vocab_pad_multiple: int
-    tied: bool
-    qk_norm: bool
-    rope_theta: float
-    eps: float
-    max_positions: int
-    vq: VQFormat | None
-
-    @classmethod
-    def from_config(cls, cfg: dict) -> "ModelSpec":
-        heads = cfg["num_attention_heads"]
-        hd = cfg.get("head_dim") or cfg["hidden_size"] // heads
-        pad = cfg["assumed"]["vocab_pad_multiple"]
-        w = cfg["weights"]
-        vq = None
-        if w["format"] == "gptvq":
-            k = round(2 ** (w["d"] * w["bits_per_dim"]))
-            vq = VQFormat(d=w["d"], k=k, group_size=w["group_size"],
-                          group_cols=w["group_cols"],
-                          codebook_bits=w["codebook_bits"])
-        return cls(
-            name=cfg["name"], d=cfg["hidden_size"],
-            n_layers=cfg["num_hidden_layers"], n_heads=heads,
-            n_kv=cfg["num_key_value_heads"], hd=hd,
-            d_ff=cfg["intermediate_size"], vocab=cfg["vocab_size"],
-            vocab_pad_multiple=pad,
-            tied=bool(cfg.get("tie_word_embeddings", False)),
-            qk_norm=cfg["model_type"] in QK_NORM_MODEL_TYPES,
-            rope_theta=float(cfg["rope_theta"]), eps=float(cfg["rms_norm_eps"]),
-            max_positions=cfg["max_position_embeddings"], vq=vq)
-
-    @property
-    def padded_vocab(self) -> int:
-        """Rows of the embedding and head as the program allocates them;
-        the rows past ``vocab`` are zero."""
-        m = self.vocab_pad_multiple
-        return -(-self.vocab // m) * m
-
-    def targets(self) -> dict[str, tuple[str, int, int]]:
-        """Every matrix of a layer: name -> (group, r=out, c=in)."""
-        H, KV, hd, D, F = self.n_heads, self.n_kv, self.hd, self.d, self.d_ff
-        return {"wq": ("attn", H * hd, D), "wk": ("attn", KV * hd, D),
-                "wv": ("attn", KV * hd, D), "wo": ("attn", D, H * hd),
-                "w_gate": ("ffn", F, D), "w_in": ("ffn", F, D),
-                "w_out": ("ffn", D, F)}
-
-    def program_config(self):
-        """The program's ModelConfig for these sizes."""
-        from repro.configs.base import ModelConfig
-
-        return ModelConfig(
-            name=self.name, family="dense", n_layers=self.n_layers,
-            d_model=self.d, n_heads=self.n_heads, n_kv_heads=self.n_kv,
-            head_dim=self.hd, d_ff=self.d_ff, vocab_size=self.vocab,
-            vocab_pad_multiple=self.vocab_pad_multiple, activation="swiglu",
-            qk_norm=self.qk_norm, tie_embeddings=self.tied,
-            rope_theta=self.rope_theta, norm_eps=self.eps,
-            max_seq_len=self.max_positions, dtype="bfloat16")
+def vq_format(cfg: dict) -> VQFormat | None:
+    """The configuration's packed-weight format; None where its weights
+    are not GPTVQ-packed."""
+    w = cfg["weights"]
+    if w["format"] != "gptvq":
+        return None
+    k = round(2 ** (w["d"] * w["bits_per_dim"]))
+    return VQFormat(d=w["d"], k=k, group_size=w["group_size"],
+                    group_cols=w["group_cols"],
+                    codebook_bits=w["codebook_bits"])
 
 
 def load(path: str | Path) -> dict:

@@ -38,7 +38,7 @@ def main(argv=None) -> int:
     devs = run.require_tpu(cell.chips)
     spec, mix = cell.spec, cell.mix
     counter = serve.CompileCounter()
-    engine = serve.build_engine(spec, cell.config, mix, args.seed)
+    engine = serve.build_engine(cell.arch, spec, cell.config, mix, args.seed)
     rates = [float(r) for r in args.rates.split(",")]
     for rate in rates:       # each rate's lengths may need other widths
         serve.warm_up(engine, spec, dict(mix, rate_per_s=rate),

@@ -1,12 +1,13 @@
-"""Seeded weights, made on the device in one jitted call.
+"""Seeded weights in the served format: the pieces every architecture's
+``raw_payload`` (``bench/arch/``) draws its arrays with.
 
-``raw_payload`` draws every array a configuration serves from the seed, in
-the type it is served in: packed GPTVQ words (uint32, ``code_bits`` per
-code), int8 codebooks with their float32 scales, and bfloat16 embedding,
-head and norm scales. ``program_params`` wraps that payload into the
-program's parameter tree (``VQLinear`` leaves with the static layout that
-``quantize_model(pack=True)`` gives for the recipe). The plain reference
-reads the same payload, so it never takes anything the program made.
+A payload holds every array a configuration serves, drawn from the seed in
+one jitted device call, in the type it is served in: packed GPTVQ words
+(uint32, ``code_bits`` per code), int8 codebooks with their float32
+scales, and bfloat16 embedding, head and norm scales. An architecture's
+``program_params`` wraps that payload into the program's parameter tree
+(``vq_linear``); its plain reference reads the same payload, so it never
+takes anything the program made.
 
 Values are drawn so the decoded weights look like a fitted model's:
 codebook entries ~ N(0, 1), scaled to int8 by each codebook's absmax as
@@ -16,12 +17,8 @@ uniform. Embedding rows past the published vocabulary are zero.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-
-from bench.spec import ModelSpec
 
 EMBED_STD = 0.02
 NORM_STD = 0.1
@@ -34,12 +31,15 @@ def seed_key(seed: int) -> jax.Array:
     return jax.random.fold_in(key, (seed >> 32) & 0x7FFFFFFF)
 
 
-def _norm(key, shape):
+def norm(key, shape):
+    """RMSNorm scales 1 + 0.1 N(0, 1), in bfloat16."""
     return (1.0 + NORM_STD * jax.random.normal(key, shape)).astype(
         jnp.bfloat16)
 
 
-def _vq_matrix(key, spec: ModelSpec, L: int, r: int, c: int) -> dict:
+def vq_matrix(key, spec, L: int, r: int, c: int) -> dict:
+    """The packed leaves of an (r=out, c=in) matrix in each of ``L``
+    layers, in ``spec.vq``'s format."""
     fmt = spec.vq
     cg, rg = fmt.plan(r, c)
     n_cg, n_bands = c // cg, r // rg
@@ -62,57 +62,12 @@ def _vq_matrix(key, spec: ModelSpec, L: int, r: int, c: int) -> dict:
             "scale_z": jnp.zeros((L, n_cg), jnp.float32)}
 
 
-@functools.partial(jax.jit, static_argnums=(1,))
-def _raw(key, spec: ModelSpec) -> dict:
-    L, D, Vp = spec.n_layers, spec.d, spec.padded_vocab
-    keys = iter(jax.random.split(key, 16))
-    rows = jnp.arange(Vp)[:, None] < spec.vocab
-    embed = jnp.where(rows, EMBED_STD * jax.random.normal(next(keys), (Vp, D)),
-                      0.0).astype(jnp.bfloat16)
-    raw = {"embed": embed, "final_norm": _norm(next(keys), (D,))}
-    if not spec.tied:
-        head = jax.random.normal(next(keys), (D, Vp)) / jnp.sqrt(D)
-        raw["lm_head"] = jnp.where(rows.T, head, 0.0).astype(jnp.bfloat16)
-    layers = {"norm1": _norm(next(keys), (L, D)),
-              "norm2": _norm(next(keys), (L, D))}
-    if spec.qk_norm:
-        layers["q_norm"] = _norm(next(keys), (L, spec.hd))
-        layers["k_norm"] = _norm(next(keys), (L, spec.hd))
-    tkey = next(keys)
-    for i, (name, (_, r, c)) in enumerate(spec.targets().items()):
-        layers[name] = _vq_matrix(jax.random.fold_in(tkey, i), spec, L, r, c)
-    raw["layers"] = layers
-    return raw
-
-
-def raw_payload(spec: ModelSpec, seed: int) -> dict:
-    """Every served array of ``spec`` from ``seed`` (one device call)."""
-    if spec.vq is None:
-        raise ValueError(f"{spec.name}: only GPTVQ-packed weights are made")
-    return _raw(seed_key(seed), spec)
-
-
-def program_params(spec: ModelSpec, raw: dict, rule: str = "default"):
-    """The program's parameter tree over ``raw``'s arrays (no copies)."""
+def vq_linear(spec, leaves: dict, r: int, c: int):
+    """The program's ``VQLinear`` over one matrix's packed leaves (no
+    copies), with the static layout ``quantize_model(pack=True)`` gives."""
     from repro.core.vq_linear import VQLinear
 
-    lay = raw["layers"]
-
-    def vq(name):
-        _, r, c = spec.targets()[name]
-        cg, rg = spec.vq.plan(r, c)
-        return VQLinear(
-            **lay[name], r=r, c=c, d=spec.vq.d, k=spec.vq.k, group_cols=cg,
-            rows_per_band=rg, scale_block=0, rule=rule)
-
-    attn = {n: vq(n) for n in ("wq", "wk", "wv", "wo")}
-    if spec.qk_norm:
-        attn["q_norm"], attn["k_norm"] = lay["q_norm"], lay["k_norm"]
-    params = {"embed": raw["embed"], "final_norm": raw["final_norm"],
-              "layers": {"norm1": lay["norm1"], "norm2": lay["norm2"],
-                         "attn": attn,
-                         "ffn": {n: vq(n) for n in
-                                 ("w_gate", "w_in", "w_out")}}}
-    if not spec.tied:
-        params["lm_head"] = raw["lm_head"]
-    return params
+    cg, rg = spec.vq.plan(r, c)
+    return VQLinear(**leaves, r=r, c=c, d=spec.vq.d, k=spec.vq.k,
+                    group_cols=cg, rows_per_band=rg, scale_block=0,
+                    rule="default")

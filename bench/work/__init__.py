@@ -1,4 +1,5 @@
-"""Required work of the kernels and of the model, counted from shapes.
+"""Required work of the kernels, counted from shapes (a model's own work
+is counted by its architecture module, ``bench/arch/``).
 
 Each function counts what the algorithm needs, not what one
 implementation happens to do, so a later kernel that does less redundant

@@ -13,11 +13,12 @@ if str(ROOT) not in sys.path:
 
 @pytest.fixture
 def small_spec():
-    """A ModelSpec of a benchmark configuration at test widths."""
+    """A decoder spec of a benchmark configuration at test widths."""
     from bench import spec as bspec
+    from bench.arch import decoder
 
     def make(name="qwen3-1.7b-vq", **kw):
-        base = bspec.ModelSpec.from_config(
+        base = decoder.spec(
             bspec.load(ROOT / "bench" / "configs" / f"{name}.json"))
         sizes = dict(d=256, n_layers=2, n_heads=4, n_kv=2, hd=64, d_ff=512,
                      vocab=300, max_positions=512)

@@ -9,6 +9,7 @@ from types import SimpleNamespace
 import pytest
 
 from bench import labels, metrics, spec as bspec, trace
+from bench.arch import decoder
 from bench.serve import Tick
 
 TESTDATA = Path(trace.__file__).resolve().parent / "testdata"
@@ -88,9 +89,10 @@ def test_idle_in_engine_spans():
 
 
 def _ctx(summary, **window):
-    spec = bspec.ModelSpec.from_config(
+    spec = decoder.spec(
         bspec.load(ROOT / "bench" / "configs" / "qwen3-1.7b-vq.json"))
-    return metrics.Context(spec=spec, window=SimpleNamespace(**window),
+    return metrics.Context(arch=decoder, spec=spec,
+                           window=SimpleNamespace(**window),
                            trace=summary, device_kind="TPU v5 lite",
                            mix={"engine": {"max_batch": 16}})
 

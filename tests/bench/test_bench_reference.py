@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from bench import reference, weights
+from bench.arch import decoder
 
 
 def test_payload_layout_matches_quantize_model(small_spec):
@@ -19,7 +20,7 @@ def test_payload_layout_matches_quantize_model(small_spec):
     from repro.models import model_zoo
 
     spec = small_spec(n_layers=1)
-    cfg = spec.program_config()
+    cfg = decoder.program_config(spec)
     model = model_zoo.build(cfg)
     dense = model.init_params(jax.random.PRNGKey(0))
     toks = jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0,
@@ -27,7 +28,7 @@ def test_payload_layout_matches_quantize_model(small_spec):
     recipe = get_recipe("2.25bpv_2d").with_quantize_overrides(
         em_iters=1, codebook_update_iters=0)
     packed, _ = quantize_model(model, dense, toks, recipe=recipe, pack=True)
-    ours = weights.program_params(spec, weights.raw_payload(spec, 3))
+    ours = decoder.program_params(spec, decoder.raw_payload(spec, 3))
 
     def layout(tree):
         def one(x):
@@ -48,8 +49,8 @@ def test_decode_matches_program_dequant(small_spec):
     from repro.core import vq_linear as vql
 
     spec = small_spec()
-    raw = weights.raw_payload(spec, 11)
-    params = weights.program_params(spec, raw)
+    raw = decoder.raw_payload(spec, 11)
+    params = decoder.program_params(spec, raw)
     for name, (group, r, c) in spec.targets().items():
         leaf = params["layers"][group][name]
         one = jax.tree.map(lambda a: a[1], leaf)
@@ -63,7 +64,7 @@ def test_decode_matches_program_dequant(small_spec):
 
 def test_padded_vocab_rows_are_zero(small_spec):
     spec = small_spec(vocab=300)
-    raw = weights.raw_payload(spec, 5)
+    raw = decoder.raw_payload(spec, 5)
     assert raw["embed"].shape[0] == spec.padded_vocab > spec.vocab
     assert not np.asarray(raw["embed"][spec.vocab:]).any()
 
@@ -85,17 +86,18 @@ def test_reference_logits_match_program_forward(small_spec, tied):
     from repro.models import model_zoo
 
     spec = small_spec(tied=tied, qk_norm=tied)
-    raw = weights.raw_payload(spec, 7)
+    raw = decoder.raw_payload(spec, 7)
     f32 = jax.tree.map(lambda a: a.astype(jnp.float32)
                        if a.dtype == jnp.bfloat16 else a, raw)
-    params = weights.program_params(spec, f32)
-    cfg = dataclasses.replace(spec.program_config(), dtype="float32")
+    params = decoder.program_params(spec, f32)
+    cfg = dataclasses.replace(decoder.program_config(spec),
+                              dtype="float32")
     model = model_zoo.build(cfg)
     rng = np.random.default_rng(0)
     seqs = [rng.integers(0, spec.vocab, n).astype(np.int32)
             for n in (37, 20)]
     positions = [np.arange(len(s)) for s in seqs]
-    got = reference.logits_at(raw, spec, seqs, positions, pad_to=16)
+    got = decoder.logits_at(raw, spec, seqs, positions, pad_to=16)
     with jax.default_matmul_precision("highest"):
         for s, g in zip(seqs, got):
             want, _, _ = model.forward(params, {"tokens": jnp.asarray(s[None])})
@@ -108,10 +110,10 @@ def test_control_is_lower_precision(small_spec):
     """The fp8 control differs from the reference by far more than float32
     rounding, and by little enough to stay a forward of the same model."""
     spec = small_spec()
-    raw = weights.raw_payload(spec, 9)
+    raw = decoder.raw_payload(spec, 9)
     seq = np.arange(40, dtype=np.int32) % spec.vocab
     pos = [np.arange(40)]
-    ref = reference.logits_at(raw, spec, [seq], pos)[0]
-    ctl = reference.logits_at(raw, spec, [seq], pos, precision="fp8")[0]
+    ref = decoder.logits_at(raw, spec, [seq], pos)[0]
+    ctl = decoder.logits_at(raw, spec, [seq], pos, precision="fp8")[0]
     rel = np.abs(ctl - ref).max() / np.abs(ref).max()
     assert 1e-3 < rel < 0.5

@@ -113,7 +113,7 @@ def test_engine_takes_the_mix_pool_size():
     cell = shrink(manifest.load_cell(CELLS[0]))
     cell.mix["engine"] = dict(cell.mix["engine"], num_blocks=13)
     spec, mix = cell.spec, cell.mix
-    engine = serve.build_engine(spec, cell.config, mix, 3)
+    engine = serve.build_engine(cell.arch, spec, cell.config, mix, 3)
     assert engine.scheduler.allocator.capacity == 12
     serve.warm_up(engine, spec, mix, 1.0, 3)
     window = serve.drive(engine, serve.traffic.schedule(mix, 3, 1.0,

@@ -1,17 +1,19 @@
-"""The required-work functions against hand counts at qwen3-1.7b's and
-yi-34b's shapes, the peaks table, and the traffic generator's fixed work."""
+"""The required-work functions (the kernels' and the decoder's model
+FLOPs) against hand counts at qwen3-1.7b's and yi-34b's shapes, the peaks
+table, and the traffic generator's fixed work."""
 import numpy as np
 import pytest
 
 from bench import manifest, spec as bspec, traffic
-from bench.work import Work, model, paged_attention, peaks, roofline
+from bench.arch import decoder
+from bench.work import Work, paged_attention, peaks, roofline
 from bench.work import vq_dequant_matmul as vq
 
 CONFIGS = manifest.ROOT / "bench" / "configs"
 
 
 def load(name):
-    return bspec.ModelSpec.from_config(bspec.load(CONFIGS / f"{name}.json"))
+    return decoder.spec(bspec.load(CONFIGS / f"{name}.json"))
 
 
 def test_vq_matmul_qwen3_wq():
@@ -39,19 +41,19 @@ def test_paged_attention_counts_live_rows_only():
 
 def test_model_flops_qwen3():
     s = load("qwen3-1.7b-vq")
-    assert model.layer_flops_per_token(s) == 2 * 28 * 50_331_648
-    assert model.head_flops(s) == 2 * 2048 * 151_936
-    assert model.attention_flops(s, 1000) == 4 * 28 * 16 * 128 * 1000
-    assert model.flops(s, 2, 1000, 1) == (2 * 2 * 28 * 50_331_648
-                                          + 4 * 28 * 16 * 128 * 1000
-                                          + 2 * 2048 * 151_936)
+    assert decoder.layer_flops_per_token(s) == 2 * 28 * 50_331_648
+    assert decoder.head_flops(s) == 2 * 2048 * 151_936
+    assert decoder.attention_flops(s, 1000) == 4 * 28 * 16 * 128 * 1000
+    assert decoder.decode_flops(s, 2, 1000, 1) == (
+        2 * 2 * 28 * 50_331_648 + 4 * 28 * 16 * 128 * 1000
+        + 2 * 2048 * 151_936)
 
 
 def test_model_flops_yi():
     s = load("yi-34b-vq")
     assert s.n_layers == 30 and s.hd == 128 and not s.tied
-    assert model.layer_flops_per_token(s) == 2 * 30 * 557_842_432
-    assert model.head_flops(s) == 2 * 7168 * 64_000
+    assert decoder.layer_flops_per_token(s) == 2 * 30 * 557_842_432
+    assert decoder.head_flops(s) == 2 * 7168 * 64_000
 
 
 def test_roofline_bound_and_peaks():

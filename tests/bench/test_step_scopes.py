@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import pytest
 
 from bench import labels, serve as bserve, spec as bspec
+from bench.arch import decoder
 
 ROOT = Path(__file__).resolve().parents[2]
 
@@ -49,12 +50,12 @@ def programs():
 
     cfg = bspec.load(ROOT / "bench" / "configs" / "qwen3-1.7b-vq.json")
     spec = dataclasses.replace(
-        bspec.ModelSpec.from_config(cfg), d=256, n_layers=2, n_heads=4,
+        decoder.spec(cfg), d=256, n_layers=2, n_heads=4,
         n_kv=2, hd=64, d_ff=512, vocab=300, max_positions=512)
     cfg["engine"] = dict(cfg["engine"], vq_matmul_impl="xla",
                          paged_attn_impl="xla", prefill_chunk=16)
     eng = bserve.build_engine(
-        spec, cfg, {"engine": {"max_batch": 4, "max_len": 64,
+        decoder, spec, cfg, {"engine": {"max_batch": 4, "max_len": 64,
                                "num_blocks": 17}}, 7)
     B, P = eng.max_batch, eng.n_pages
     dec = eng._decode_fn.lower(
